@@ -47,23 +47,15 @@ def run() -> None:
         hbm_bytes_per_step=(bm * bk + bk * bn) * 2,
         vmem_bytes=(bm * bk + bk * bn) * 2 + bm * bn * 4,
     )
-    # spmv_ell: R x W slab + resident vector; SpMV is memory-bound by nature
-    R, W, V = 256, 256, 65536
+    # spmv_ell: R x W value slab + gathered-vector slab; SpMV is
+    # memory-bound by nature
+    R, W = 256, 128
     _analyze(
-        "spmv_ell.256x256",
+        "spmv_ell.256x128",
         flops_per_step=2 * R * W,
-        hbm_bytes_per_step=(R * W) * (4 + 4),   # val + col stream; vec resident
-        vmem_bytes=(R * W) * 8 + V * 4 + R * 4,
-        notes=f"vector {V} f32 resident; gather stays on-chip",
-    )
-    # windowed variant for huge vectors
-    Wn = 65536
-    _analyze(
-        "spmv_ell.windowed",
-        flops_per_step=2 * R * W,
-        hbm_bytes_per_step=(R * W) * 8,
-        vmem_bytes=(R * W) * 8 + Wn * 4 + R * 4,
-        notes="window slice resident instead of full vector",
+        hbm_bytes_per_step=(R * W) * (4 + 4),   # val + gathered vec stream
+        vmem_bytes=(R * W) * 8 + R * 4,
+        notes="vec[col] gathered by XLA in front of the kernel",
     )
     # moe_gmm: (tm,dk)x(dk,fn) bf16, weight tile revisited per m-tile
     tm = dk = fn = 128

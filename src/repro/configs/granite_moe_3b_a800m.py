@@ -1,6 +1,6 @@
 """granite-moe-3b-a800m — 40 experts, top-8.
 [moe] 32L d_model=1536 24H (GQA kv=8) d_ff=512/expert vocab=49155
-[hf:ibm-granite/granite-3.0-1b-a400m-base; hf]
+[hf:ibm-granite/granite-3.0-3b-a800m-base; hf]
 """
 from repro.configs.base import ArchConfig, register
 
@@ -15,5 +15,5 @@ CONFIG = register(ArchConfig(
     vocab=49155,
     moe_experts=40,
     moe_topk=8,
-    source="[hf:ibm-granite/granite-3.0-1b-a400m-base; hf]",
+    source="[hf:ibm-granite/granite-3.0-3b-a800m-base; hf]",
 ))

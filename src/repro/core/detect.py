@@ -28,6 +28,7 @@ import dataclasses
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax import core as jcore
 from jax.extend import core as jex_core
@@ -141,8 +142,8 @@ class Ctx:
         # requested many times per jaxpr.  Keyed on id() — the atoms are
         # owned by self.jaxpr, which we hold, so ids are stable.
         self._peel_cache: Dict[int, Atom] = {}
-        self._prov_cache: Dict[int, Tuple[List[Any], List[Eqn]]] = {}
-        self._subjaxpr_cache: Dict[int, Any] = {}
+        self._prov_cache: Dict[Tuple, Tuple[List[Any], List[Eqn]]] = {}
+        self._subjaxpr_cache: Dict[Tuple, Any] = {}
         # semantic-validation verdicts, keyed by the validator on the
         # participating atom ids: identical subgraphs reached through
         # different patterns validate once (and reuse one sampled input
@@ -242,13 +243,17 @@ class Ctx:
 
     # -- provenance ----------------------------------------------------------
 
-    def provenance(self, atom) -> Tuple[List[Any], List[Eqn]]:
-        """Transitive producer closure: (leaf vars [invars/constvars], eqns
-        in original topological order).  Memoized per atom — callers must
-        not mutate the returned lists."""
-        cached = self._prov_cache.get(id(atom))
+    def provenance(self, atom, stop: Tuple[Atom, ...] = ()
+                   ) -> Tuple[List[Any], List[Eqn]]:
+        """Transitive producer closure: (leaf vars [invars/constvars, and
+        the atoms in ``stop``], eqns in original topological order).
+        Memoized per (atom, stop) — callers must not mutate the returned
+        lists."""
+        key = (id(atom),) + tuple(id(a) for a in stop)
+        cached = self._prov_cache.get(key)
         if cached is not None:
             return cached
+        stop_ids = {id(a) for a in stop}
         eqns: Dict[int, Eqn] = {}
         leaves: List[Any] = []
         seen = set()
@@ -258,7 +263,7 @@ class Ctx:
             if isinstance(a, jex_core.Literal) or id(a) in seen:
                 continue
             seen.add(id(a))
-            eqn = self.prod(a)
+            eqn = None if id(a) in stop_ids else self.prod(a)
             if eqn is None:
                 if a not in leaves:
                     leaves.append(a)
@@ -267,18 +272,21 @@ class Ctx:
             for iv in eqn.invars:
                 stack.append(iv)
         ordered = [eqns[i] for i in sorted(eqns)]
-        self._prov_cache[id(atom)] = (leaves, ordered)
+        self._prov_cache[key] = (leaves, ordered)
         return leaves, ordered
 
-    def eval_subgraph(self, out_atom, leaf_values: Dict[Any, np.ndarray]):
+    def eval_subgraph(self, out_atom, leaf_values: Dict[Any, np.ndarray],
+                      stop: Tuple[Atom, ...] = ()):
         """Concretely evaluate the provenance subgraph of ``out_atom`` given
-        values for its leaves — the semantic validation step.  The built
-        sub-jaxpr is cached per atom, so repeated validations (multiple
-        trials, multiple candidate patterns over the same subgraph) only
-        pay jaxpr construction once."""
-        sub = self._subjaxpr_cache.get(id(out_atom))
+        values for its leaves (cut at the ``stop`` atoms) — the semantic
+        validation step.  The built sub-jaxpr is cached per (atom, stop),
+        so repeated validations (multiple trials, multiple candidate
+        patterns over the same subgraph) only pay jaxpr construction
+        once."""
+        key = (id(out_atom),) + tuple(id(a) for a in stop)
+        sub = self._subjaxpr_cache.get(key)
         if sub is None:
-            leaves, eqns = self.provenance(out_atom)
+            leaves, eqns = self.provenance(out_atom, stop)
             # The parent's debug_info describes the parent's arity; newer
             # jax asserts arg_names/result_paths lengths match, so the
             # sub-jaxpr must drop it entirely.
@@ -286,7 +294,7 @@ class Ctx:
                 constvars=(), invars=list(leaves), outvars=[out_atom],
                 eqns=eqns, debug_info=None,
             )
-            self._subjaxpr_cache[id(out_atom)] = sub
+            self._subjaxpr_cache[key] = sub
         vals = []
         for lf in sub.invars:
             if lf in leaf_values:
@@ -326,14 +334,17 @@ class Pat:
 
 
 class B(Pat):
-    """Bind the (peeled) atom to a name; if already bound, require identity."""
+    """Bind the (peeled) atom to a name; if already bound, require identity.
+    ``peel=False`` binds the operand exactly as the equation consumes it."""
 
-    def __init__(self, name: str, pred: Optional[Callable] = None):
+    def __init__(self, name: str, pred: Optional[Callable] = None,
+                 peel: bool = True):
         self.name = name
         self.pred = pred
+        self.peel = peel
 
     def match(self, ctx, atom, env):
-        a = ctx.peel(atom)
+        a = ctx.peel(atom) if self.peel else atom
         if self.pred is not None and not self.pred(ctx, a):
             return
         if self.name in env:
@@ -549,6 +560,34 @@ def _validate_row_expansion(ctx: Ctx, row_atom, row_ptr_var, nnz: int,
     return ok
 
 
+def _dispatch_sources(ctx: Ctx, combine) -> Optional[Tuple[Atom, Atom]]:
+    """``(idx, gate)`` of a combine ``einsum('tke,tk->te', onehot(idx),
+    gate)``: the gate is the 2-D operand of its dot_general, idx the one
+    (T, K) integer array nearest upstream of the one-hot operand.  Either
+    may be computed in the program (a router), not only passed in."""
+    eqn = ctx.prod(ctx.peel(combine))
+    if eqn is None or eqn.primitive.name != "dot_general":
+        return None
+    ops = sorted(eqn.invars, key=lambda a: getattr(a.aval, "ndim", 0))
+    if [getattr(a.aval, "ndim", 0) for a in ops] != [2, 3]:
+        return None
+    gate, onehot = ops
+    shape = onehot.aval.shape[:2]
+    found, seen, stack = [], set(), [onehot]
+    while stack:
+        a = stack.pop()
+        if isinstance(a, jex_core.Literal) or id(a) in seen:
+            continue
+        seen.add(id(a))
+        if a.aval.shape == shape and np.issubdtype(a.aval.dtype, np.integer):
+            found.append(a)
+            continue
+        producer = ctx.prod(a)
+        if producer is not None:
+            stack.extend(producer.invars)
+    return (found[0], gate) if len(found) == 1 else None
+
+
 def _validate_onehot_dispatch(ctx: Ctx, combine_atom, idx_var, gate_var,
                               n_experts: int) -> bool:
     """combine[t,e] must equal sum_k gate[t,k] * (idx[t,k] == e).
@@ -559,18 +598,25 @@ def _validate_onehot_dispatch(ctx: Ctx, combine_atom, idx_var, gate_var,
         return cached
     t, k = idx_var.aval.shape
     rng = np.random.default_rng(0)
-    idx = rng.integers(0, n_experts, size=(t, k)).astype(np.int32)
-    gate = rng.standard_normal((t, k)).astype(np.float32)
+    idx = rng.integers(0, n_experts, size=(t, k)).astype(idx_var.aval.dtype)
+    gate = rng.standard_normal((t, k)).astype(gate_var.aval.dtype)
     expect = np.zeros((t, n_experts), np.float32)
     for ti in range(t):
         for ki in range(k):
-            expect[ti, idx[ti, ki]] += gate[ti, ki]
+            expect[ti, idx[ti, ki]] += np.float32(gate[ti, ki])
     try:
-        got = ctx.eval_subgraph(combine_atom, {idx_var: idx, gate_var: gate})
+        got = ctx.eval_subgraph(combine_atom, {idx_var: idx, gate_var: gate},
+                                stop=(idx_var, gate_var))
     except Exception:
         ctx.validation_cache[key] = False
         return False
-    ok = got.shape == expect.shape and np.allclose(got, expect, atol=1e-5)
+    # a bf16 program rounds the gates: allow the dtype's own rounding, far
+    # below the O(1) error of any dispatch that is not the top-k one-hot
+    eps = float(jnp.finfo(got.dtype).eps) if jnp.issubdtype(
+        got.dtype, jnp.floating) else 0.0
+    tol = max(1e-5, 2 * eps)
+    ok = got.shape == expect.shape and np.allclose(
+        np.asarray(got, np.float32), expect, rtol=tol, atol=tol)
     ctx.validation_cache[key] = ok
     return ok
 
@@ -1012,14 +1058,18 @@ class MoeMatcher(Matcher):
 
         def expert_mm(w):
             # einsum('td,edf->etf', x, w) lowers to
-            # transpose(0,2,1)(dot_general(w, x; contract d, no batch))
-            inner = P("dot_general", B(w), B("x", pred=_is_2d),
+            # transpose(0,2,1)(dot_general(w, x; contract d, no batch)).
+            # operands bind unpeeled, as the matmuls consume them: in a
+            # model x is the (T, D) reshape of the (B, S, D) activations and
+            # each weight a squeezed slice of the layer-stacked parameters
+            x = B("x", pred=_is_2d, peel=False)
+            inner = P("dot_general", B(w, peel=False), x,
                       params=lambda p: p.get("dimension_numbers")
                       == (((1,), (1,)), ((), ())))
             return Alt(
                 P("transpose", inner,
                   params=lambda p: tuple(p.get("permutation", ())) == (0, 2, 1)),
-                P("dot_general", B("x", pred=_is_2d), B(w),
+                P("dot_general", x, B(w, peel=False),
                   params=lambda p: p.get("dimension_numbers")
                   == (((1,), (1,)), ((), ()))),
             )
@@ -1028,7 +1078,7 @@ class MoeMatcher(Matcher):
                      Comm("mul", expert_mm("wg"), P("logistic", expert_mm("wg"))),
                      expert_mm("wu"))
         self.y_pat = P(
-            "dot_general", h_pat, B("wd"),
+            "dot_general", h_pat, B("wd", peel=False),
             params=lambda p: p.get("dimension_numbers")
             == (((2,), (1,)), ((0,), (0,))))
 
@@ -1044,14 +1094,10 @@ class MoeMatcher(Matcher):
             return None
         n_experts = combine.aval.shape[1]
         for env in self.y_pat.match(ctx, y, {}):
-            leaves, _ = ctx.provenance(ctx.peel(combine))
-            int_leaves = [l for l in leaves
-                          if np.issubdtype(getattr(l.aval, "dtype", np.float32),
-                                           np.integer)]
-            float_leaves = [l for l in leaves if l not in int_leaves]
-            if len(int_leaves) != 1 or len(float_leaves) != 1:
+            sources = _dispatch_sources(ctx, combine)
+            if sources is None:
                 continue
-            idx_var, gate_var = int_leaves[0], float_leaves[0]
+            idx_var, gate_var = sources
             if not _validate_onehot_dispatch(ctx, ctx.peel(combine),
                                              idx_var, gate_var, n_experts):
                 continue

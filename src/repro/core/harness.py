@@ -339,7 +339,7 @@ def _spmv_ell_host(b: Binding, ctx: CallCtx, *, ell):
 def _binding_to_csr(b: Binding):
     from repro.sparse.formats import CSR
 
-    cols = int(np.asarray(b["iv"]).shape[0])
+    cols = int(b["iv"].shape[0])
     if "rowstr" in b:
         return CSR(val=b["a"], col_ind=b["colidx"], row_ptr=b["rowstr"],
                    shape=(b["rows"], cols))
@@ -401,7 +401,7 @@ def _binding_to_csr_spmm(b: Binding):
     """Like _binding_to_csr but the column count comes from the dense
     operand's leading dim (the paper's Fig. 9 `cols` invariant)."""
     bb = dict(b)
-    bb["iv"] = jnp.zeros((int(np.asarray(b["dense"]).shape[0]),))
+    bb["iv"] = jnp.zeros((int(b["dense"].shape[0]),))
     return _binding_to_csr(bb)
 
 

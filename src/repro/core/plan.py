@@ -13,8 +13,8 @@ compile-once artifact, in two layers:
   definitive ``(harness, schedule)`` selection, the rewritten program is
   baked into ONE ``jax.jit``-compiled callable.  Marshaled operands (the
   ELL/BCSR buffers the data plane built) are hoisted out of the traced
-  body as captured device-resident constants; fused epilogues trace
-  in-line.  Steady-state dispatch is then: cheap guard check → one jitted
+  body and passed to the jitted plan as device-array arguments; fused
+  epilogues trace in-line.  Steady-state dispatch is then: cheap guard check → one jitted
   call.  Guards are O(arity): aval (shape/dtype) checks on every leaf,
   plus *identity* checks on the leaves that feed marshal clauses — JAX
   arrays are immutable, so object identity proves the hoisted buffers are
@@ -40,6 +40,7 @@ Environment knobs:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -718,6 +719,28 @@ class ExecutablePlan:
         }
 
 
+def _host_array(const) -> Optional[np.ndarray]:
+    """The numpy array behind a jaxpr const: the const itself, or the
+    caller's array that a tracing-time ``TypedNdArray`` holds in ``val``.
+    None for device (jax) arrays."""
+    if isinstance(const, np.ndarray):
+        return const
+    val = getattr(const, "val", None)
+    return val if isinstance(val, np.ndarray) else None
+
+
+def _with_cached_facts(recorded, rebuilt):
+    """``rebuilt`` (the traced stand-in for a recorded buffer) with the
+    host-side facts the recorded object had cached
+    (``functools.cached_property`` values such as
+    ``BCSR.all_block_rows_nonempty``), which a tracer cannot recompute."""
+    cached = getattr(recorded, "__dict__", None)
+    if cached and rebuilt is not recorded and type(rebuilt) is type(recorded):
+        for k, v in cached.items():
+            rebuilt.__dict__.setdefault(k, v)
+    return rebuilt
+
+
 def bake_plan(*, closed_jaxpr, matches, needed, recorder: PlanRecorder,
               raw_flat, flat, in_tree, out_tree, report,
               mode: str, platform: str, enabled: bool,
@@ -764,18 +787,37 @@ def bake_plan(*, closed_jaxpr, matches, needed, recorder: PlanRecorder,
             ctx.fuse = s.fuse
         return s.harness
 
+    # The marshaled device buffers enter the plan as jit ARGUMENTS: a
+    # closed-over device array is embedded in the compiled program as a
+    # literal, which for an HBM-scale repack means serializing it into the
+    # HLO and holding a second copy on the device.
+    hoisted = {aid: tuple(s.buffers) for aid, s in slots.items()}
+    aids = list(hoisted)
+    buf_flat, buf_tree = jax.tree_util.tree_flatten([hoisted[a] for a in aids])
+    dyn = [i for i, x in enumerate(buf_flat) if isinstance(x, jax.Array)]
+    live: Dict[int, Tuple] = {}
+
     def ctx_factory(m):
         s = slots[id(m.anchor_eqn)]
-        return CallCtx(mode=mode, cache=_PlanBuffers(s.buffers),
+        return CallCtx(mode=mode, cache=_PlanBuffers(live[id(m.anchor_eqn)]),
                        format=m.format, platform=platform,
                        schedule=s.schedule, epilogue=m.epilogue,
                        fuse=s.fuse)
 
-    def baked(*leaves):
+    def baked(buf_args, *leaves):
+        vals = list(buf_flat)
+        for i, x in zip(dyn, buf_args):
+            vals[i] = x
+        for aid, bufs in zip(aids, jax.tree_util.tree_unflatten(buf_tree,
+                                                                vals)):
+            live[aid] = tuple(_with_cached_facts(o, b)
+                              for o, b in zip(hoisted[aid], bufs))
         return run_rewritten(closed_jaxpr, matches, select, list(leaves),
                              ctx_factory, needed=needed)
 
-    jitted = jax.jit(baked, donate_argnums=donate)
+    jitted = functools.partial(
+        jax.jit(baked, donate_argnums=tuple(i + 1 for i in donate)),
+        tuple(buf_flat[i] for i in dyn))
     traced = any(isinstance(x, jax.core.Tracer) for x in flat)
     if traced:
         # Baking under a transform trace (the call that resolved the
@@ -800,19 +842,21 @@ def bake_plan(*, closed_jaxpr, matches, needed, recorder: PlanRecorder,
         jax.block_until_ready(jitted(*warm))
 
     guards = [_Guard(pos, raw_flat[pos]) for pos in sorted(guard_positions)]
-    # Closure captures: jax keeps them as live references in consts, so
-    # the interpreter re-reads them every call while the jitted plan
-    # froze their values at trace time.  Immutable (jax) consts cannot
-    # diverge; EVERY writable numpy const — marshal source or plain
-    # operand (e.g. a captured bias) — gets a per-dispatch EXACT content
-    # fingerprint so in-place mutation busts the plan like it would have
-    # changed the interpreter's output.  Exact hashing is O(bytes) per
-    # dispatch, so captures past the bound refuse to bake instead of
-    # silently making the "zero-overhead" path slower than the
-    # interpreter — pass big matrices as arguments (identity-guarded,
-    # free) or TrackedArray (O(1) version) to get a plan.
-    writable = [c for c in closed_jaxpr.consts
-                if isinstance(c, np.ndarray) and c.flags.writeable]
+    # Closure captures: jax keeps a numpy capture in consts as a live
+    # reference (wrapped in a TypedNdArray whose ``val`` is the caller's
+    # array), so eager execution and the interpreter see an in-place
+    # mutation while the jitted plan froze the values at trace time.
+    # Immutable (jax) consts cannot diverge; EVERY writable numpy const —
+    # marshal source or plain operand (e.g. a captured bias) — gets a
+    # per-dispatch EXACT content fingerprint so in-place mutation busts
+    # the plan and the call re-bakes on the new values, as eager would
+    # compute.  Exact hashing is O(bytes) per dispatch, so captures past
+    # the bound refuse to bake instead of silently making the
+    # "zero-overhead" path slower than the interpreter — pass big
+    # matrices as arguments (identity-guarded, free) or TrackedArray (O(1)
+    # version) to get a plan.
+    writable = [h for h in map(_host_array, closed_jaxpr.consts)
+                if h is not None and h.flags.writeable]
     big = [c for c in writable if c.nbytes > CONST_GUARD_MAX_BYTES]
     if big:
         raise PlanBakeError(
@@ -823,7 +867,6 @@ def bake_plan(*, closed_jaxpr, matches, needed, recorder: PlanRecorder,
     selections = [(m, slots[id(m.anchor_eqn)].harness.name) for m in matches]
     schedules = [slots[id(m.anchor_eqn)].schedule for m in matches]
     fuses = [slots[id(m.anchor_eqn)].fuse for m in matches]
-    hoisted = {aid: tuple(s.buffers) for aid, s in slots.items()}
     trace_servable = all(
         s.harness.jit_safe or getattr(s.harness, "vjp", None) is not None
         for s in slots.values())

@@ -104,8 +104,9 @@ def run_rewritten(closed_jaxpr,
     anchor_map = {id(m.anchor_eqn): m for m in matches}
     if needed is None:
         needed = needed_eqn_ids(closed_jaxpr, matches)
+    dead = _dead_after(jaxpr, anchor_map)
 
-    for eqn in jaxpr.eqns:
+    for i, eqn in enumerate(jaxpr.eqns):
         m = anchor_map.get(id(eqn))
         if m is not None:
             if m.variant == "scan_body":
@@ -114,18 +115,49 @@ def run_rewritten(closed_jaxpr,
             else:
                 _eval_anchor(eqn, m, select, read, write, ctx_factory,
                              on_select, contain)
-            continue
-        if id(eqn) not in needed:
-            continue
-        subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
-        ans = eqn.primitive.bind(*subfuns, *map(read, eqn.invars), **bind_params)
-        if eqn.primitive.multiple_results:
-            for ov, v in zip(eqn.outvars, ans):
-                write(ov, v)
-        else:
-            write(eqn.outvars[0], ans)
+        elif id(eqn) in needed:
+            subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
+            ans = eqn.primitive.bind(*subfuns, *map(read, eqn.invars),
+                                     **bind_params)
+            if eqn.primitive.multiple_results:
+                for ov, v in zip(eqn.outvars, ans):
+                    write(ov, v)
+            else:
+                write(eqn.outvars[0], ans)
+        for v in dead[i]:
+            env.pop(v, None)
 
     return [read(v) for v in jaxpr.outvars]
+
+
+def _dead_after(jaxpr, anchor_map) -> List[List[Any]]:
+    """For each equation, the variables no later equation (or harness
+    binding) reads.  Host mode evaluates eagerly, so dropping them frees
+    their device buffers as it goes: an unrolled decode slices every
+    layer's weights out of the stacked params, and holding all the slices
+    at once would need a second copy of the model."""
+    last: Dict[Any, int] = {}
+
+    def use(atom, i):
+        if not isinstance(atom, (int, float, bool, jex_core.Literal)):
+            last[atom] = i
+
+    for i, eqn in enumerate(jaxpr.eqns):
+        for v in eqn.invars:
+            use(v, i)
+        m = anchor_map.get(id(eqn))
+        if m is not None:
+            for v in m.binding.values():
+                use(v, i)
+        for v in eqn.outvars:
+            last.setdefault(v, i)
+    for v in jaxpr.outvars:
+        if not isinstance(v, jex_core.Literal):
+            last.pop(v, None)
+    dead: List[List[Any]] = [[] for _ in jaxpr.eqns]
+    for v, i in last.items():
+        dead[i].append(v)
+    return dead
 
 
 def apply_epilogue(out, bias, epilogue: str):

@@ -426,6 +426,14 @@ def _dense_to_bcsr128(dense):
     return _dense_to_bcsr(dense, (128, 128))
 
 
+@M.edge("CSR", "BCSR128x128", name="csr_to_bcsr128x128")
+def _csr_to_bcsr128(csr):
+    """Tiles the nonzeros directly: the MXU kernel's matrices are the ones
+    too large to densify on the way."""
+    from repro.sparse.convert import csr_to_bcsr
+    return csr_to_bcsr(csr, block_shape=(128, 128))
+
+
 # ---------------------------------------------------------------------------
 # Builtin repacks (single-hop fallbacks; also the graph-equivalence oracle).
 # ---------------------------------------------------------------------------

@@ -882,7 +882,7 @@ forall(0 <= i < rows) {
 
 HARNESS jnp.ell implements spmv_ell, spmv_jds
   formats ELL, JDS;
-  default_for cpu;
+  default_for cpu, tpu;
 """
 
 BUILTIN_SPECS["spmm"] = """

@@ -10,7 +10,7 @@ DESIGN.md):
     bsr_spmm — block-sparse (BCSR) x dense on the MXU, scalar-prefetched
                block indices (the cuSPARSE csrmv analogue, re-blocked for
                the systolic array)
-    spmv_ell — ELL/JDS row-slab SpMV on the VPU with VMEM-resident vector
+    spmv_ell — ELL/JDS row-slab SpMV on the VPU over the gathered vector
     moe_gmm  — group-aligned ragged grouped matmul (megablocks-style), the
                MoE expert FFN hot loop
 """

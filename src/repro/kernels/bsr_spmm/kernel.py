@@ -61,7 +61,12 @@ def _bsr_spmm_kernel(block_row_ref, block_col_ref,   # scalar prefetch (SMEM)
 
     a = blocks_ref[0]                                # (bm, bk)
     b = rhs_ref[...]                                 # (bk, bn)
-    out_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    # f32 operands get f32 products (the MXU's default passes round them
+    # to bf16), matching the elementwise naive program the pass replaces
+    prec = (jax.lax.Precision.HIGHEST if jnp.float32 in (a.dtype, b.dtype)
+            else None)
+    out_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32,
+                            precision=prec)
 
     if epilogue is not None or bias_kind:
         is_last = jnp.logical_or(

@@ -31,5 +31,5 @@ def compiler_params(dimension_semantics: Optional[Tuple[str, ...]]):
     (empty when None, so untuned calls stay byte-identical)."""
     if dimension_semantics is None:
         return {}
-    return {"compiler_params": pltpu.TPUCompilerParams(
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=tuple(dimension_semantics))}

@@ -71,15 +71,17 @@ def moe_ffn(x: jax.Array,      # (T, D)
             if dimension_semantics else None)
     dk_d, fn_f = _tile(D, dk), _tile(F, fn)   # contract D / output F (up)
     dk_f, fn_d = _tile(F, dk), _tile(D, fn)   # contract F / output D (down)
+    # each matmul accumulates in f32 and rounds to the activation dtype,
+    # the rounding points of the naive dense-dispatch program
     g = gmm_pallas(xs, wg, tile_expert, tm=tm, fn=fn_f, dk=dk_d,
                    dimension_semantics=dims, interpret=interpret)
     u = gmm_pallas(xs, wu, tile_expert, tm=tm, fn=fn_f, dk=dk_d,
                    dimension_semantics=dims, interpret=interpret)
-    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    h = jax.nn.silu(g.astype(x.dtype)) * u.astype(x.dtype)
     y = gmm_pallas(h, wd, tile_expert, tm=tm, fn=fn_d, dk=dk_f,
                    dimension_semantics=dims, interpret=interpret)  # (Tp, D)
-    flat_g = gate.reshape(-1).astype(jnp.float32)
-    contrib = y[dest] * flat_g[:, None]
+    flat_g = gate.reshape(-1).astype(x.dtype).astype(jnp.float32)
+    contrib = y.astype(x.dtype).astype(jnp.float32)[dest] * flat_g[:, None]
     out = jax.ops.segment_sum(contrib, flat_t, num_segments=T)
     return out.astype(x.dtype)
 

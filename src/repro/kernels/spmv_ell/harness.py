@@ -25,7 +25,6 @@ from repro.core.spec import harness
 @harness("""
 HARNESS pallas.ell implements spmv_ell, spmv_jds
   formats ELL, JDS;
-  default_for tpu;
   tune rows_per_slab in {256, 64, 128, 512};
   tune dimsem in {arbitrary, parallel};
   fuse epilogue;

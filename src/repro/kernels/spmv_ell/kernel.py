@@ -1,30 +1,35 @@
-"""ELL/JDS row-slab SpMV on the VPU with a VMEM-resident vector.
+"""ELL/JDS row-slab SpMV on the VPU.
 
-Hardware adaptation (DESIGN.md §2): the paper's JDS layout exists to give
-GPU warps coalesced loads down jagged diagonals.  On TPU the analogous
-resource is VMEM locality: rows are sorted by nnz (the JDS permutation,
-kept as a marshaled invariant), padded to a lane-aligned width (ELL slab),
-and processed in (rows_per_slab, width) VMEM tiles.  The gather
-vec[col[i,j]] stays on-chip because the full dense vector is pinned in VMEM
-across the grid (BlockSpec index_map constant-0 — Pallas keeps the block
-resident); for vectors larger than VMEM the ops layer falls back to the
-column-windowed variant below.
+Hardware adaptation: the paper's JDS layout exists to give GPU warps
+coalesced loads down jagged diagonals.  On TPU the analogous resource is
+dense VMEM tiles: rows are sorted by nnz (the JDS permutation, kept as a
+marshaled invariant), padded to a lane-aligned width (ELL slab), and
+processed in (rows_per_slab, width) tiles.
+
+The gather ``vec[col[i, j]]`` runs as an XLA gather in front of the
+kernel, which then streams the gathered slab beside the value slab.
+Mosaic lowers no gather from an arbitrary-length vector (only 2-D
+in-register gathers), so a vector resident in VMEM cannot be indexed by
+``col`` inside the kernel; with the gather outside there is also no
+bound on the vector's length.
 
 Schedule parameters (declared as ``tune`` clauses in the HARNESS block and
 swept by the autotuner — no module constants):
 
   rows_per_slab        rows per grid step; trades grid overhead against
                        VMEM working set per step.
-  dimension_semantics  Mosaic grid annotation ('parallel' row slabs when
-                       the slab-independent accumulation allows it).
+  dimension_semantics  Mosaic grid annotation ('parallel' row slabs: each
+                       slab writes its own output block).
 
-Fused epilogue: the kernels optionally apply ``(+bias) -> relu|silu``
+Fused epilogue: the kernel optionally applies ``(+bias) -> relu|silu``
 in-register before the single output store, eliminating the full
 output-size HBM round-trip an unfused activation pays.
 
-Grid: (num_slabs,) over row slabs.
-VMEM per step: slab val+col (2 x R x W x 4B) + vector + out row block.
-For R=256, W=256, vec 64K f32: 0.5 MiB + 0.25 MiB — double-buffer safe.
+Grid: (num_slabs,) over row slabs.  The output (and bias) are carried as
+(rows, 1) columns: a row sum keeps the sublane layout of the slab, and a
+(R, 1) block is legal for every R that is a multiple of 8.
+VMEM per step: val + gathered slabs (2 x R x W x 4B) + out column.
+For R=256, W=128: 256 KiB — double-buffer safe.
 """
 from __future__ import annotations
 
@@ -38,15 +43,12 @@ from jax.experimental import pallas as pl
 from repro.kernels.common import apply_epilogue_inregister, compiler_params
 
 
-def _spmv_ell_kernel(val_ref, col_ref, vec_ref, *rest, epilogue=None,
-                     has_bias=False):
+def _spmv_ell_kernel(val_ref, xg_ref, *rest, epilogue=None, has_bias=False):
     bias_ref = rest[0] if has_bias else None
     out_ref = rest[-1]
     val = val_ref[...].astype(jnp.float32)       # (R, W)
-    col = col_ref[...]                           # (R, W)
-    vec = vec_ref[...].astype(jnp.float32)       # (V,)
-    gathered = jnp.take(vec, col, axis=0)        # VMEM gather on lanes
-    acc = jnp.sum(val * gathered, axis=1)
+    xg = xg_ref[...].astype(jnp.float32)         # (R, W) = vec[col]
+    acc = jnp.sum(val * xg, axis=1, keepdims=True)
     bias = bias_ref[...].astype(jnp.float32) if has_bias else None
     out_ref[...] = apply_epilogue_inregister(acc, bias, epilogue)
 
@@ -64,96 +66,23 @@ def spmv_ell_pallas(val: jax.Array,   # (rows, width)
                     interpret: bool = False) -> jax.Array:
     rows, width = val.shape
     assert rows % rows_per_slab == 0, (rows, rows_per_slab)
-    num_slabs = rows // rows_per_slab
-    grid = (num_slabs,)
+    grid = (rows // rows_per_slab,)
     has_bias = bias is not None
-    in_specs = [
-        pl.BlockSpec((rows_per_slab, width), lambda i: (i, 0)),
-        pl.BlockSpec((rows_per_slab, width), lambda i: (i, 0)),
-        pl.BlockSpec((vec.shape[0],), lambda i: (0,)),  # resident
-    ]
-    args = [val, col, vec]
+    slab = pl.BlockSpec((rows_per_slab, width), lambda i: (i, 0))
+    column = pl.BlockSpec((rows_per_slab, 1), lambda i: (i, 0))
+    in_specs = [slab, slab]
+    args = [val, jnp.take(vec, col, axis=0)]
     if has_bias:
-        in_specs.append(pl.BlockSpec((rows_per_slab,), lambda i: (i,)))
-        args.append(bias)
+        in_specs.append(column)
+        args.append(bias.reshape(rows, 1))
     fn = pl.pallas_call(
         functools.partial(_spmv_ell_kernel, epilogue=epilogue,
                           has_bias=has_bias),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((rows_per_slab,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((rows,), jnp.float32),
+        out_specs=column,
+        out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         interpret=interpret,
         **compiler_params(dimension_semantics),
     )
-    return fn(*args)
-
-
-def _spmv_ell_windowed_kernel(val_ref, col_ref, vec_ref, *rest, window,
-                              epilogue=None, has_bias=False):
-    """Column-windowed variant: the slab's column indices are window-local
-    (marshaling pre-subtracts the window base), so only a (window,) slice of
-    the vector is resident per step.  The epilogue applies on the last
-    window visit, when the row accumulator is complete."""
-    bias_ref = rest[0] if has_bias else None
-    out_ref = rest[-1]
-    w = pl.program_id(1)
-    nw = pl.num_programs(1)
-    val = val_ref[...].astype(jnp.float32)[:, 0, :]   # (R, W)
-    col = col_ref[...][:, 0, :]
-    vec = vec_ref[...].astype(jnp.float32)
-    gathered = jnp.take(vec, col, axis=0)
-
-    @pl.when(w == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += jnp.sum(val * gathered, axis=1)
-
-    if epilogue is not None or has_bias:
-        @pl.when(w == nw - 1)
-        def _():
-            bias = bias_ref[...].astype(jnp.float32) if has_bias else None
-            out_ref[...] = apply_epilogue_inregister(out_ref[...], bias,
-                                                     epilogue)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("rows_per_slab", "window",
-                                    "dimension_semantics", "epilogue",
-                                    "interpret"))
-def spmv_ell_windowed_pallas(val: jax.Array,   # (rows, n_windows, width)
-                             col: jax.Array,   # (rows, n_windows, width)
-                             vec: jax.Array,   # (V,) with V % window == 0
-                             rows_per_slab: int = 256,
-                             window: int = 4096,
-                             dimension_semantics: Optional[Tuple[str, ...]]
-                             = None,
-                             epilogue: Optional[str] = None,
-                             bias: Optional[jax.Array] = None,
-                             interpret: bool = False) -> jax.Array:
-    rows, n_windows, width = val.shape
-    assert rows % rows_per_slab == 0
-    assert vec.shape[0] == n_windows * window
-    grid = (rows // rows_per_slab, n_windows)
-    has_bias = bias is not None
-    in_specs = [
-        pl.BlockSpec((rows_per_slab, 1, width), lambda i, w: (i, w, 0)),
-        pl.BlockSpec((rows_per_slab, 1, width), lambda i, w: (i, w, 0)),
-        pl.BlockSpec((window,), lambda i, w: (w,)),
-    ]
-    args = [val, col, vec]
-    if has_bias:
-        in_specs.append(pl.BlockSpec((rows_per_slab,), lambda i, w: (i,)))
-        args.append(bias)
-    fn = pl.pallas_call(
-        functools.partial(_spmv_ell_windowed_kernel, window=window,
-                          epilogue=epilogue, has_bias=has_bias),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((rows_per_slab,), lambda i, w: (i,)),
-        out_shape=jax.ShapeDtypeStruct((rows,), jnp.float32),
-        interpret=interpret,
-        **compiler_params(dimension_semantics),
-    )
-    return fn(*args)
+    return fn(*args).reshape(rows)

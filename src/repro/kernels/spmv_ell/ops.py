@@ -1,9 +1,8 @@
-"""jit'd wrapper for the ELL slab SpMV kernel: padding + variant dispatch.
+"""Wrapper for the ELL slab SpMV kernel: row padding to the slab size.
 
 All schedule parameters (``rows_per_slab``, ``dimension_semantics``) flow
 through from the HARNESS block's tune clauses; this layer only normalizes
-shapes (row padding to the slab size, bias padding) and picks the
-VMEM-resident vs column-windowed kernel.
+shapes (row padding to the slab size, bias padding).
 """
 from __future__ import annotations
 
@@ -13,15 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.spmv_ell.kernel import (
-    spmv_ell_pallas,
-    spmv_ell_windowed_pallas,
-)
+from repro.kernels.spmv_ell.kernel import spmv_ell_pallas
 from repro.kernels.spmv_ell.ref import spmv_ell_ref
-
-# Vector sizes above this use the column-windowed variant (vector slice per
-# window instead of the whole vector resident in VMEM).
-_VMEM_VEC_LIMIT = 1 << 20  # 1M elements (4 MiB f32)
 
 
 def spmv_ell(val: jax.Array, col: jax.Array, vec: jax.Array,
@@ -33,9 +25,8 @@ def spmv_ell(val: jax.Array, col: jax.Array, vec: jax.Array,
     """ELL SpMV with row padding to the slab size.
 
     ``dimension_semantics`` is the per-slab grid annotation name
-    ('parallel' | 'arbitrary'); the windowed variant forces the window
-    dimension to 'arbitrary' (it accumulates).  ``epilogue``/``bias``
-    apply the detected fused epilogue in-register.
+    ('parallel' | 'arbitrary').  ``epilogue``/``bias`` apply the detected
+    fused epilogue in-register.
     """
     rows, width = val.shape
     if epilogue is not None and bias is not None and (
@@ -57,50 +48,12 @@ def spmv_ell(val: jax.Array, col: jax.Array, vec: jax.Array,
         col = jnp.pad(col, ((0, pad), (0, 0)))
         if bias is not None:
             bias = jnp.pad(bias, (0, pad))
-    if vec.shape[0] <= _VMEM_VEC_LIMIT:
-        dims = (dimension_semantics,) if dimension_semantics else None
-        out = spmv_ell_pallas(val, col, vec, rows_per_slab=rows_per_slab,
-                              dimension_semantics=dims,
-                              epilogue=epilogue, bias=bias,
-                              interpret=interpret)
-    else:
-        out = _windowed(val, col, vec, rows_per_slab, interpret,
-                        dimension_semantics=dimension_semantics,
-                        epilogue=epilogue, bias=bias)
+    dims = (dimension_semantics,) if dimension_semantics else None
+    out = spmv_ell_pallas(val, col, vec, rows_per_slab=rows_per_slab,
+                          dimension_semantics=dims,
+                          epilogue=epilogue, bias=bias,
+                          interpret=interpret)
     return out[:rows]
-
-
-def _windowed(val, col, vec, rows_per_slab, interpret, window: int = 1 << 16,
-              dimension_semantics: Optional[str] = None,
-              epilogue: Optional[str] = None,
-              bias: Optional[jax.Array] = None):
-    rows, width = val.shape
-    v = vec.shape[0]
-    pad_v = (-v) % window
-    if pad_v:
-        vec = jnp.pad(vec, (0, pad_v))
-    n_windows = vec.shape[0] // window
-    # split each row's slots by column window; pad each window's slot list
-    # to `width` (worst case all slots in one window).  The marshaling layer
-    # does this once per matrix; here we do it with jnp for completeness.
-    wid = col // window
-    val3 = jnp.zeros((rows, n_windows, width), val.dtype)
-    col3 = jnp.zeros((rows, n_windows, width), col.dtype)
-    # position within (row, window): stable cumsum trick
-    onehot = jax.nn.one_hot(wid, n_windows, dtype=jnp.int32)     # (R,W,nw)
-    pos = jnp.cumsum(onehot, axis=1) - onehot                    # (R,W,nw)
-    pos = jnp.take_along_axis(pos, wid[..., None], axis=2)[..., 0]
-    r = jnp.arange(rows)[:, None] + jnp.zeros_like(col)
-    val3 = val3.at[r, wid, pos].set(val)
-    col3 = col3.at[r, wid, pos].set(col % window)
-    dims = ((dimension_semantics, "arbitrary")
-            if dimension_semantics else None)
-    return spmv_ell_windowed_pallas(val3, col3, vec,
-                                    rows_per_slab=rows_per_slab,
-                                    window=window,
-                                    dimension_semantics=dims,
-                                    epilogue=epilogue, bias=bias,
-                                    interpret=interpret)
 
 
 def spmv_ell_oracle(val, col, vec):

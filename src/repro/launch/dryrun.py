@@ -14,8 +14,11 @@ Each cell can run in a subprocess (--all) so a failure or OOM in one cell
 never kills the sweep; results are cached incrementally as JSON.
 """
 # The VERY FIRST lines, before ANY other import (jax locks device count on
-# first init):
+# first init).  The placeholder pod is CPU devices: pin the platform so
+# this process (and the --all children that inherit its environment)
+# never claims an attached accelerator.
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))
@@ -31,7 +34,6 @@ import jax          # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import SHAPES, all_archs, get_arch, shape_skips  # noqa: E402
-from repro import compat                                            # noqa: E402
 from repro.launch.mesh import make_production_mesh, mesh_rules      # noqa: E402
 from repro.models import build_model                                # noqa: E402
 from repro.models import spec as S                                  # noqa: E402
@@ -312,7 +314,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         bshard = TS.batch_shardings(model, shape, mesh, rules)
         abs_opt = jax.eval_shape(lambda p: O.adamw_init(opt_cfg, p), abs_params)
         abs_batch = model.input_specs(shape)
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=(pshard, oshard, bshard),
@@ -324,7 +326,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         bshard = TS.batch_shardings(model, shape, mesh, rules)
         cshard = TS.prefill_cache_shardings(model, shape, mesh, rules)
         abs_batch = model.input_specs(shape)
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step, in_shardings=(pshard, bshard),
                 out_shardings=(None, cshard),
@@ -333,7 +335,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         step = TS.make_serve_step(model, "decode")
         bsh = TS.batch_shardings(model, shape, mesh, rules)
         specs = model.input_specs(shape)
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=(pshard, bsh["cache"], bsh["tokens"], bsh["pos"]),

@@ -8,6 +8,9 @@ validate loop logged in EXPERIMENTS.md §Perf.
     PYTHONPATH=src python -m repro.launch.perf --all
 """
 import os
+# CPU placeholder devices only: pinned so neither this process nor its
+# per-experiment children claim an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))

@@ -469,8 +469,7 @@ def _moe_grouped_shardmap(x, gate, idx, wg, wu, wd, *,
     bspec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0],
               *([None] * 2))
     wspec = P(model_axis, None, None)
-    from repro import compat
-    return compat.shard_map(
+    return jax.shard_map(
         local_fn,
         in_specs=(bspec, bspec, bspec, wspec, wspec, wspec),
         out_specs=bspec,

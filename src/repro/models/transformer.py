@@ -152,9 +152,8 @@ def _constrain(cfg, spec_tree, params):
         def local(w):
             return jax.lax.all_gather(w, "data", axis=ax, tiled=True)
 
-        from repro import compat
-        return compat.shard_map(local, in_specs=storage, out_specs=compute,
-                                check_vma=False)(value)
+        return jax.shard_map(local, in_specs=storage, out_specs=compute,
+                             check_vma=False)(value)
 
     return jax.tree.map(
         resolve, spec_tree, params,

@@ -320,20 +320,34 @@ class Engine:
         return out
 
     def replay_solo(self, req: Request) -> List[int]:
-        """Re-decode a finished request's stream solo ON THIS ENGINE: a
-        fresh single-request cache at the smallest batch bucket, the same
-        compiled prefill/install/decode the batched path used.  Returns
-        exactly ``len(req.tokens)`` greedy tokens — the reference the
-        request-level shadow compares against."""
-        B = self.buckets.batch_bucket(1)
-        S = self.buckets.seq_bucket(req.prompt_len + req.max_new_tokens)
-        cache = self.model.init_cache(B, S)
+        """Re-decode a finished request's stream alone ON THIS ENGINE,
+        through the same compiled prefill/install/decode the batched path
+        used: the request sits in slot 0 and each decode step runs at the
+        ``(batch, seq)`` bucket it was served at (``req.decode_shapes``),
+        the other rows empty.  Returns exactly ``len(req.tokens)`` greedy
+        tokens — the reference the request-level shadow compares against.
+
+        The served shapes matter.  Each bucket is its own XLA program, and
+        on TPU the batch-8 and batch-1 programs round a row differently in
+        the last bits, which greedy decoding through a deep model turns
+        into different tokens.  What the *other* rows hold does not change
+        a row's result, so a replay at the served shapes is exact and any
+        difference is the batched path's fault (slot map, compaction,
+        cache moves)."""
+        shapes = list(req.decode_shapes[:len(req.tokens) - 1])
+        shape = shapes[0] if shapes else (
+            self.buckets.batch_bucket(1),
+            self.buckets.seq_bucket(req.prompt_len + req.max_new_tokens))
+        cache = self.model.init_cache(*shape)
         logits, caches = self._prefill(self.params, req.prompt[None, :])
-        cache = self._install(cache, caches, 0, req.prompt_len, S)
+        cache = self._install(cache, caches, 0, req.prompt_len, shape[1])
         toks = [int(np.argmax(np.asarray(logits)[0]))]
-        tokens = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B,), np.int32)
-        while len(toks) < len(req.tokens):
+        for B, S in shapes:
+            if (B, S) != shape:
+                cache = self.model.cache_resize(cache, B=B, max_seq=S)
+                shape = (B, S)
+            tokens = np.zeros((B, 1), np.int32)
+            pos = np.zeros((B,), np.int32)
             tokens[0, 0] = toks[-1]
             pos[0] = req.prompt_len + len(toks) - 1
             logits, cache = self._decode(self.params, cache, tokens, pos)
@@ -436,6 +450,7 @@ class Engine:
                 self.metrics.record_decode_fault()
                 continue
             r.tokens.append(int(nxt[i]))
+            r.decode_shapes.append((tb, ts))
         self.metrics.record_step(
             dt, batch=tb, active=len(active),
             queue_depth=self.scheduler.queue_depth,

@@ -204,6 +204,7 @@ class FrontDoor:
                 self._lose(req, now)
                 continue
             req.tokens.clear()
+            req.decode_shapes.clear()
             req.ttft_s = None
             req.prefill_s = None
             if self.submit(req):

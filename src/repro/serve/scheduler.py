@@ -53,6 +53,9 @@ class Request:                        # stateful records, not values
     deadline_s: Optional[float] = None
     # filled by the engine as the request progresses
     tokens: List[int] = dataclasses.field(default_factory=list)
+    # (batch, seq) bucket of the decode step behind each of tokens[1:]
+    decode_shapes: List[Tuple[int, int]] = dataclasses.field(
+        default_factory=list)
     ttft_s: Optional[float] = None        # arrival -> first token
     finish_t: Optional[float] = None
     prefill_s: Optional[float] = None

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.sparse.formats import (
-    BCSR, CSR, ELL, JDS, bcsr_from_dense, ell_from_csr, jds_from_csr,
+    BCSR, CSR, ELL, JDS, bcsr_from_csr, ell_from_csr, jds_from_csr,
 )
 
 
@@ -32,14 +32,7 @@ def csr_to_jds(csr: CSR) -> JDS:
 
 
 def csr_to_bcsr(csr: CSR, block_shape=(8, 128)) -> BCSR:
-    dense = np.asarray(csr.todense())
-    bm, bn = block_shape
-    rows, cols = dense.shape
-    pr = (-rows) % bm
-    pc = (-cols) % bn
-    if pr or pc:
-        dense = np.pad(dense, ((0, pr), (0, pc)))
-    return bcsr_from_dense(dense, block_shape)
+    return bcsr_from_csr(csr, block_shape)
 
 
 def csr_to_dense(csr: CSR):

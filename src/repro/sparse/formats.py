@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
 from typing import Tuple
 
 import jax
@@ -259,13 +260,62 @@ def ell_from_csr(csr: CSR, width: int | None = None, sort_rows: bool = True,
     w = max(lane, ((w + lane - 1) // lane) * lane)
     val = np.zeros((rows, w), dtype=valv.dtype)
     col = np.zeros((rows, w), dtype=np.int32)
-    for i in range(rows):
-        src = perm[i]
-        n = int(nnz_per_row[src])
-        val[i, :n] = valv[row_ptr[src]:row_ptr[src] + n]
-        col[i, :n] = colv[row_ptr[src]:row_ptr[src] + n]
+    # entry k of source row r lands in slab row inv[r], slot k - row_ptr[r]
+    src_row = np.repeat(np.arange(rows), nnz_per_row)
+    inv = np.empty(rows, np.int64)
+    inv[perm] = np.arange(rows)
+    slot = np.arange(src_row.shape[0]) - row_ptr[src_row]
+    val[inv[src_row], slot] = valv[:src_row.shape[0]]
+    col[inv[src_row], slot] = colv[:src_row.shape[0]]
     return ELL(val=jnp.asarray(val), col=jnp.asarray(col),
                perm=jnp.asarray(perm), shape=csr.shape)
+
+
+def bcsr_from_csr(csr: CSR, block_shape=(8, 128)) -> BCSR:
+    """Tile a CSR matrix without densifying it: the same BCSR as
+    ``bcsr_from_dense(pad(csr.todense()), block_shape)`` — nonzero tiles in
+    block-row-major order, one explicit zero tile at block-column 0 for
+    every empty block-row — built from the nonzeros alone."""
+    bm, bn = block_shape
+    rows, cols = csr.shape
+    R, C = -(-rows // bm) * bm, -(-cols // bn) * bn
+    row_ptr = np.asarray(csr.row_ptr)
+    nnz = int(row_ptr[-1])
+    val = np.asarray(csr.val)[:nnz]
+    col = np.asarray(csr.col_ind)[:nnz].astype(np.int64)
+    row = np.repeat(np.arange(rows, dtype=np.int64), np.diff(row_ptr))
+    keep = val != 0
+    row, col, val = row[keep], col[keep], val[keep]
+    nbc = C // bn
+    tile_of = (row // bm) * nbc + col // bn
+    tiles, where = np.unique(tile_of, return_inverse=True)
+    need = tiles.shape[0] * bm * bn * val.dtype.itemsize
+    host = os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+    if need > host:
+        # a scattered matrix puts about one nonzero in each tile: refuse
+        # before the allocation rather than let the OS kill the process
+        raise MemoryError(f"BCSR{bm}x{bn} of this matrix holds "
+                          f"{tiles.shape[0]} tiles ({need} bytes), more "
+                          f"than the host's {host} bytes")
+    blocks = np.zeros((tiles.shape[0], bm, bn), dtype=val.dtype)
+    np.add.at(blocks, (where, row % bm, col % bn), val)
+    # a tile whose entries cancel is zero in the dense form: drop it
+    live = blocks.reshape(blocks.shape[0], -1).any(axis=1)
+    tiles, blocks = tiles[live], blocks[live]
+    empty = np.setdiff1d(np.arange(R // bm), tiles // nbc)
+    order = np.argsort(np.concatenate([tiles, empty * nbc]), kind="stable")
+    blocks = np.concatenate(
+        [blocks, np.zeros((empty.shape[0], bm, bn), blocks.dtype)])[order]
+    tiles = np.concatenate([tiles, empty * nbc])[order]
+    counts = np.bincount(tiles // nbc, minlength=R // bm)
+    return BCSR(
+        blocks=jnp.asarray(blocks),
+        block_col=jnp.asarray((tiles % nbc).astype(np.int32)),
+        block_rowptr=jnp.asarray(
+            np.concatenate([[0], np.cumsum(counts)]).astype(np.int32)),
+        shape=(R, C),
+        block_shape=(bm, bn),
+    )
 
 
 def bcsr_from_dense(dense, block_shape=(8, 128)) -> BCSR:

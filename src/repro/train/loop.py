@@ -36,8 +36,7 @@ def train_loop(model: Model, opt_cfg: O.AdamWConfig, loop_cfg: LoopConfig,
     step_fn = TS.make_train_step(model, opt_cfg)
     mesh_ctx = None
     if mesh is not None:
-        from repro import compat
-        mesh_ctx = compat.use_mesh(mesh)
+        mesh_ctx = jax.set_mesh(mesh)
         mesh_ctx.__enter__()   # shard_map/constraints need the context mesh
         pshard = TS.param_shardings(model, mesh, rules)
         oshard = TS.opt_state_shardings(model, opt_cfg, mesh, rules)

@@ -140,8 +140,7 @@ def prefill_cache_shardings(model: Model, shape: ShapeConfig, mesh: Mesh,
     not fit replicated)."""
     b = rules.get("batch", "data")
     msize = mesh.shape.get("model", 1)
-    from repro import compat
-    with compat.use_mesh(mesh):  # prefill applies sharding constraints
+    with jax.set_mesh(mesh):  # prefill applies sharding constraints
         cache_abs = jax.eval_shape(
             lambda p, batch: model.prefill(p, batch)[1],
             model.abstract_params(), model.input_specs(shape))

@@ -87,6 +87,8 @@ def test_prefill_matches_decode(arch):
         # and decode (many small programs) therefore disagree by ~4e-3
         # per silu site, which 16 recurrent layers amplify past tol with
         # occasional argmax flips.  Not a cache/position logic bug.
+        # Re-checked on jax 0.9.0: still a max logit difference of ~1.1
+        # with argmax flips, so the xfail stays.
         pytest.xfail("XLA-CPU fused-kernel logistic approximation; "
                      "prefill/decode program shapes differ")
     if cfg.moe_experts:

@@ -246,8 +246,11 @@ def test_numpy_inplace_mutation_busts_plan():
 
 def test_non_marshal_numpy_capture_mutation_busts_plan():
     """EVERY writable numpy closure capture is const-guarded, not just
-    marshal sources: the interpreter reads the live reference each call
-    (e.g. a captured bias), so the plan must see the mutation too."""
+    marshal sources.  jax keeps a numpy capture in the jaxpr's consts as a
+    ``TypedNdArray`` around the caller's array, so eager ``fn`` and the
+    interpreter see an in-place edit (e.g. of a captured bias) while
+    ``jax.jit(fn)`` keeps the traced values; the plan answers as eager
+    ``fn`` does."""
     bias = np.zeros(8, np.float32)
 
     def fn(x):
@@ -268,8 +271,8 @@ def test_non_marshal_numpy_capture_mutation_busts_plan():
 def test_large_numpy_capture_single_element_edit_busts_plan():
     """Const guards fingerprint EXACTLY: a one-element edit of a capture
     above the 64KB sampled-hash threshold — invisible to the sampled
-    fingerprint — must still bust the plan, because the interpreter
-    re-reads the capture exactly every call."""
+    fingerprint — must still bust the plan, because eager ``fn`` and the
+    interpreter read the capture's current values every call."""
     n = (1 << 16) // 4 + 4096                         # > _SMALL bytes of f32
     bias = np.zeros(n, np.float32)
 
@@ -288,10 +291,11 @@ def test_large_numpy_capture_single_element_edit_busts_plan():
 
 
 def test_closure_captured_numpy_mutation_busts_plan():
-    """jax keeps closure-captured numpy operands as live references in
-    ``consts``, so the interpreter path sees in-place mutation through
-    the marshal fingerprint — a baked plan must too, via its const
-    guards."""
+    """jax keeps closure-captured numpy operands in ``consts`` as
+    ``TypedNdArray`` wrappers of the caller's arrays, so eager ``naive``
+    and the interpreter path see an in-place mutation (``jax.jit(naive)``
+    does not) — a baked plan must see it too, via its const guards, and
+    re-bake on the new values."""
     csr, vec = _problem()
     rows, nnz = csr.rows, csr.nnz
     val_np = np.array(np.asarray(csr.val))            # writable capture
@@ -337,7 +341,9 @@ def test_numpy_scalar_arg_keys_like_compile_dict():
 def test_huge_writable_capture_refuses_to_bake():
     """Exact-guarding a capture is O(bytes) per dispatch: past the bound
     the entry stays on the interpreter (visible in plan_info) instead of
-    silently hashing the whole matrix every call."""
+    silently hashing the whole matrix every call.  The interpreter reads
+    the capture through its ``TypedNdArray`` wrapper, so an in-place edit
+    shows, as it does in eager ``fn``."""
     big = np.zeros(P.CONST_GUARD_MAX_BYTES // 4 + 1024, np.float32)
 
     def fn(x):

@@ -63,8 +63,8 @@ from repro.train import optim as O, train_step as TS
 cfg = smoke_config(get_arch("olmoe-1b-7b")).replace(
     spmd_constraints=True, mesh_axis_sizes=(("data", 2), ("model", 2)))
 model = build_model(cfg)
-from repro import compat
-mesh = compat.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(2, 2)
 rules = mesh_rules(False)
 opt_cfg = O.AdamWConfig()
 step = TS.make_train_step(model, opt_cfg)
@@ -75,7 +75,7 @@ abs_opt = jax.eval_shape(lambda p: O.adamw_init(opt_cfg, p), abs_params)
 import jax.numpy as jnp
 abs_batch = {"tokens": jax.ShapeDtypeStruct((4, 32), jnp.int32),
              "labels": jax.ShapeDtypeStruct((4, 32), jnp.int32)}
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     lowered = jax.jit(step, in_shardings=(pshard, oshard, None),
                       donate_argnums=(0, 1)).lower(
         abs_params, abs_opt, abs_batch)

@@ -75,14 +75,87 @@ def test_spmv_ell_shapes(rows, cols, density, skew):
 
 
 def test_spmv_ell_windowed():
-    csr = random_csr(128, 512, density=0.05, seed=11)
-    ell = ell_from_csr(csr)
+    """Columns far past the row count (the case a column window once
+    served): the gather in front of the kernel reads the whole vector,
+    however long."""
+    csr = random_csr(128, 4096, density=0.01, seed=11)
+    ell = ell_from_csr(csr, lane=128)
     vec = jnp.asarray(np.random.default_rng(4).standard_normal(
-        512).astype(np.float32))
+        4096).astype(np.float32))
     ref = ell_ops.spmv_ell_oracle(ell.val, ell.col, vec)
-    from repro.kernels.spmv_ell.ops import _windowed
-    out = _windowed(ell.val, ell.col, vec, 8, True, window=128)
-    np.testing.assert_allclose(out[:128], ref, atol=1e-4, rtol=1e-4)
+    out = ell_ops.spmv_ell(ell.val, ell.col, vec, rows_per_slab=64,
+                           dimension_semantics="parallel", interpret=True)
+    np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
+
+
+def test_spmv_ell_fused_bias_relu():
+    csr = random_csr(200, 300, density=0.05, seed=5)
+    ell = ell_from_csr(csr)
+    rng = np.random.default_rng(6)
+    vec = jnp.asarray(rng.standard_normal(300).astype(np.float32))
+    bias = jnp.asarray(rng.standard_normal(200).astype(np.float32))
+    ref = jnp.maximum(ell_ops.spmv_ell_oracle(ell.val, ell.col, vec) + bias,
+                      0.0)
+    out = ell_ops.spmv_ell(ell.val, ell.col, vec, epilogue="relu", bias=bias,
+                           interpret=True)
+    np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
+
+
+def _ell_by_row_loop(csr, lane, sort_rows):
+    """The row-at-a-time ELL packing ``ell_from_csr`` vectorizes."""
+    row_ptr, valv, colv = (np.asarray(a) for a in
+                           (csr.row_ptr, csr.val, csr.col_ind))
+    nnz = np.diff(row_ptr)
+    perm = (np.argsort(-nnz, kind="stable") if sort_rows
+            else np.arange(csr.rows))
+    w = max(lane, -(-int(nnz.max()) // lane) * lane)
+    val = np.zeros((csr.rows, w), valv.dtype)
+    col = np.zeros((csr.rows, w), np.int32)
+    for i, src in enumerate(perm):
+        n = int(nnz[src])
+        val[i, :n] = valv[row_ptr[src]:row_ptr[src] + n]
+        col[i, :n] = colv[row_ptr[src]:row_ptr[src] + n]
+    return val, col
+
+
+@pytest.mark.parametrize("sort_rows", [True, False])
+@pytest.mark.parametrize("seed", range(4))
+def test_ell_from_csr_matches_row_loop(seed, sort_rows):
+    csr = random_csr(100 + seed, 80, density=0.1, seed=seed,
+                     skew=float(seed % 2))
+    ell = ell_from_csr(csr, lane=8, sort_rows=sort_rows)
+    val, col = _ell_by_row_loop(csr, 8, sort_rows)
+    np.testing.assert_array_equal(np.asarray(ell.val), val)
+    np.testing.assert_array_equal(np.asarray(ell.col), col)
+
+
+@pytest.mark.parametrize("block_shape", [(8, 128), (16, 128), (128, 128)])
+@pytest.mark.parametrize("seed", range(3))
+def test_bcsr_from_csr_matches_dense_tiling(seed, block_shape):
+    """Tiling the nonzeros gives the BCSR of the padded dense matrix, with
+    its explicit zero tile for every empty block-row."""
+    from repro.sparse.formats import bcsr_from_csr, bcsr_from_dense
+    rng = np.random.default_rng(seed)
+    r, c = (int(v) for v in rng.integers(1, 300, 2))
+    csr = random_csr(r, c, density=float(rng.random() * 0.1), seed=seed)
+    bm, bn = block_shape
+    dense = np.pad(np.asarray(csr.todense()), ((0, -r % bm), (0, -c % bn)))
+    got, want = bcsr_from_csr(csr, block_shape), bcsr_from_dense(
+        dense, block_shape)
+    assert got.shape == want.shape and got.block_shape == want.block_shape
+    for a, b in ((got.blocks, want.blocks), (got.block_col, want.block_col),
+                 (got.block_rowptr, want.block_rowptr)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+
+
+def test_random_graph_csr_is_column_stochastic():
+    from repro.sparse.random import random_graph_csr
+    d = np.asarray(random_graph_csr(64, 6, seed=3).todense())
+    col_sums = d.sum(axis=0)
+    np.testing.assert_allclose(col_sums[col_sums > 0], 1.0, rtol=1e-6)
+    assert not np.diag(d).any()
 
 
 @pytest.mark.parametrize("T,D,F,E,K,tm", [

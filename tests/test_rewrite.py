@@ -129,3 +129,32 @@ def test_autotune_policy(problem):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-4)
     # winner is cached per signature
     assert len(REGISTRY._autotune_cache) >= 1
+
+
+def test_host_interpreter_frees_dead_values():
+    """Host mode runs the program eagerly: a value no later equation reads
+    is dropped as it dies, so an unrolled loop over stacked weights holds
+    one layer's slice at a time, not one per layer."""
+    from jax.extend import core as jex_core
+
+    from repro.core.rewrite import run_rewritten
+    live = []
+    # an identity whose eager rule runs in Python when the interpreter binds
+    # it (a host callback would run whenever the asynchronous dispatch
+    # reaches it), counting the arrays alive at that point of the program
+    count_p = jex_core.Primitive("count_live_arrays")
+    count_p.def_impl(lambda x: live.append(len(jax.live_arrays())) or x)
+    count_p.def_abstract_eval(lambda x: x)
+
+    def f(w, x):
+        for j in range(w.shape[0]):
+            x = count_p.bind(jnp.tanh(x @ w[j]))
+        return x
+
+    w = jnp.full((6, 16, 16), 0.1)
+    x = jnp.ones((2, 16))
+    jaxpr = jax.make_jaxpr(f)(w, x)
+    out, = run_rewritten(jaxpr, [], None, [w, x], None)
+    # the six slices of w and their products never pile up
+    assert len(live) == 6 and max(live) <= live[0] + 2, live
+    np.testing.assert_allclose(out, f(w, x), rtol=1e-6)

@@ -319,6 +319,44 @@ def test_engine_eviction_midstream_does_not_corrupt_neighbors():
     assert late.tokens == _solo_stream(list(late.prompt), 2)
 
 
+class ShapeRoundingModel(MockModel):
+    """A decode whose rows depend on the batch bucket, as a TPU program's
+    rounding does: the hash also folds in the batch size."""
+
+    def decode(self, params, cache, tokens, pos):
+        B = np.asarray(tokens).shape[0]
+        return super().decode(params, cache, tokens, np.asarray(pos) + B)
+
+
+def test_replay_solo_decodes_at_served_shapes():
+    """A request served in a batch of 4, then of 2, replays exactly only
+    at those shapes; at batch 1 it would differ from the second token on.
+    Request shadowing therefore stays clean."""
+    cfg = ServeConfig(buckets=BucketPolicy(batch=(1, 2, 4), seq=(32,)),
+                      use_lilac=False, jit_prefill=False,
+                      request_shadow_rate=1.0)
+    eng = Engine(ShapeRoundingModel(), params=None, config=cfg)
+    reqs = [_req(plen=3, new=n) for n in (2, 3, 6, 6)]
+    for r in reqs:
+        assert eng.submit(r)
+    eng.run_until_idle()
+    long = reqs[-1]
+    assert long.decode_shapes == [(4, 32), (4, 32), (2, 32), (2, 32),
+                                  (2, 32)]
+    for r in reqs:
+        assert eng.replay_solo(r) == r.tokens
+        assert len(r.decode_shapes) == len(r.tokens) - 1
+    alone = _req(plen=3, new=6)
+    alone.prompt = long.prompt
+    alone.tokens = list(long.tokens)
+    alone.decode_shapes = [(1, 32)] * 5
+    at_batch_1 = eng.replay_solo(alone)
+    assert at_batch_1[0] == long.tokens[0]
+    assert at_batch_1[1] != long.tokens[1]
+    assert eng.metrics.request_shadow_checks == 4
+    assert eng.metrics.request_shadow_divergences == 0
+
+
 def test_engine_rejects_unbucketable_and_full_queue():
     eng = _mock_engine(batch=(1,), seq=(16,), queue_capacity=1)
     assert not eng.submit(_req(plen=20, new=4))      # 24 > max seq 16
@@ -482,3 +520,20 @@ def test_vector_pos_decode_matches_scalar(small_lm):
     import jax
     for a, b in zip(jax.tree.leaves(c_s), jax.tree.leaves(c_v)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_bf16_moe_decode_matches_moe_ffn():
+    """The decode step of a bf16 MoE model carries the dense-dispatch MoE
+    as the model builds it: ``x`` a reshape of the activations, each
+    expert weight a slice of the layer-stacked parameters, and the gate
+    and expert indices computed by the router.  The detector must match
+    it there, not only in a hand-written layer."""
+    import jax
+    from repro.serve import build_engine
+    eng = build_engine("granite-moe-3b-a800m", smoke=True, config=ServeConfig(
+        buckets=BucketPolicy(batch=(2,), seq=(16,)), prewarm_on_start=False))
+    assert jax.tree.leaves(eng.params)[0].dtype == np.dtype("bfloat16")
+    report = eng.prewarm()
+    assert report["baked"] == 1
+    comps = [m.computation for m, _ in eng._decode.last_selections]
+    assert comps.count("moe_ffn") == eng.model.cfg.n_layers, comps

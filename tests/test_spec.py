@@ -444,7 +444,9 @@ def test_deprecation_shims_still_work():
 # harness._register_builtins), as (name, platforms, formats, jit_safe)
 # per computation plus the per-platform defaults.  Spec-driven
 # registration must reproduce it exactly — same fingerprint, same
-# autotune cache keys.
+# autotune cache keys.  One default has moved since: on TPU, ELL/JDS
+# matches default to jnp.ell, because pallas.ell gathers the vector in
+# XLA in front of its kernel and so only adds an HBM round trip.
 _EXPECTED = {
     "spmv_csr": [
         ("jnp.segment", ("cpu", "tpu"), ("CSR", "COO"), True),
@@ -479,8 +481,8 @@ _EXPECTED["spmv_jds"] = _EXPECTED["spmv_ell"]
 _EXPECTED_DEFAULTS = {
     ("spmv_csr", "cpu"): "jnp.segment", ("spmv_csr", "tpu"): "jnp.segment",
     ("spmv_coo", "cpu"): "jnp.segment", ("spmv_coo", "tpu"): "jnp.segment",
-    ("spmv_ell", "cpu"): "jnp.ell", ("spmv_ell", "tpu"): "pallas.ell",
-    ("spmv_jds", "cpu"): "jnp.ell", ("spmv_jds", "tpu"): "pallas.ell",
+    ("spmv_ell", "cpu"): "jnp.ell", ("spmv_ell", "tpu"): "jnp.ell",
+    ("spmv_jds", "cpu"): "jnp.ell", ("spmv_jds", "tpu"): "jnp.ell",
     ("spmm_csr", "cpu"): "jnp.segment", ("spmm_csr", "tpu"): "pallas.bcsr",
     ("dotproduct", "cpu"): "jnp.dot", ("dotproduct", "tpu"): "jnp.dot",
     ("gemv", "cpu"): "jnp.dot", ("gemv", "tpu"): "jnp.dot",
@@ -509,7 +511,7 @@ def test_selection_parity_spot_checks():
     assert r.select("spmv_csr", "CSR", "cpu", "trace").name == "jnp.segment"
     assert r.select("spmv_csr", "CSR", "cpu", "host",
                     policy="jnp.ell").name == "jnp.ell"
-    assert r.select("spmv_ell", "ELL", "tpu", "trace").name == "pallas.ell"
+    assert r.select("spmv_ell", "ELL", "tpu", "trace").name == "jnp.ell"
     assert r.select("spmm_csr", "CSR", "tpu", "host").name == "pallas.bcsr"
     assert r.select("moe_ffn", "MOE", "cpu", "trace").name == "jnp.capacity"
     # trace mode still filters host-only harnesses
