@@ -80,6 +80,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.jsonstore import JsonStore
 
 SCHEMA_VERSION = 4
@@ -879,11 +880,18 @@ class Autotuner:
     def select(self, comp: str, fmt: str, platform: str, mode: str,
                cands: Sequence[Any], binding: Dict[str, Any], ctx,
                default_name: Optional[str] = None):
-        """Pick a harness from ``cands`` for this call signature.
+        """Pick a harness from ``cands`` for this call signature, under
+        the ``lilac.tune`` span.
 
         Returns the chosen Harness, or None to tell the registry to fall
         back to its per-platform default path.
         """
+        with spans.span("lilac.tune"):
+            return self._select(comp, fmt, platform, mode, cands, binding,
+                                ctx, default_name)
+
+    def _select(self, comp, fmt, platform, mode, cands, binding, ctx,
+                default_name):
         if not cands:
             return None
         q = self._quarantine_store()

@@ -49,6 +49,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import spans
+
 _SMALL = 1 << 16  # full-hash threshold in bytes
 
 _MISSING = object()
@@ -127,6 +129,15 @@ def nbytes_of(x) -> int:
     except TypeError:
         itemsize = 4
     return int(np.prod(shape)) * itemsize if len(shape) else itemsize
+
+
+def _produced(sp: spans.span, val):
+    """The bytes of a conversion's product, on its ``lilac.marshal`` span
+    and the ``lilac.marshal_bytes`` counter."""
+    import jax
+    n = sum(nbytes_of(x) for x in jax.tree_util.tree_leaves(val))
+    sp.set(bytes=n)
+    spans.count("lilac.marshal_bytes", n)
 
 
 def tree_nbytes(val) -> int:
@@ -503,9 +514,11 @@ class MarshalingCache:
         from repro.core import faults
         if faults.ACTIVE is not None:
             faults.fail("marshal_raise", spec_name)
-        t0 = time.perf_counter()
-        val = compute()
-        cost = time.perf_counter() - t0
+        with spans.span("lilac.marshal") as sp:
+            t0 = time.perf_counter()
+            val = compute()
+            cost = time.perf_counter() - t0
+            _produced(sp, val)
         self._spec_cost[spec_name] = cost
         self._insert(key, val, cost)
         return val
@@ -632,42 +645,46 @@ class DataPlane(MarshalingCache):
             if fallback is None:
                 raise KeyError(f"no conversion path {src}({loader.fmt})"
                                f"->{dst} and no fallback repack")
-            t0 = time.perf_counter()
-            val = fallback()
-            cost = time.perf_counter() - t0
+            with spans.span("lilac.marshal") as sp:
+                t0 = time.perf_counter()
+                val = fallback()
+                cost = time.perf_counter() - t0
+                val = self._maybe_device(dst, val)
+                _produced(sp, val)
             self._spec_cost[f"{src}->{dst}"] = cost
             ps.build_seconds += cost
             ps.last_path = (f"{src}!fallback", dst)
-            val = self._maybe_device(dst, val)
             self._insert(key, val, cost)
             return val
 
         start_fmt, path, _ = plan
         paid = 0.0
         path_names = [start_fmt] + [e.dst for e in path]
-        if start_fmt in cached_vals:
-            # ride an already-cached intermediate (possibly built for a
-            # DIFFERENT harness) — the plan-level sharing win
-            val = self._store[cached_vals[start_fmt]]
-            self._store.move_to_end(cached_vals[start_fmt])
-            self.stats.shared_edge_hits += 1
-            ps.shared_prefix_hits += 1
-            ps.rides += 1
-            ps.shared_prefix_bytes += tree_nbytes(val)
-        else:
-            val, dt = loader.run(binding)
-            paid += dt
-            self.stats.loader_runs += 1
-            val = self._maybe_device(start_fmt, val)
-            self._insert(self._node_key(src, start_fmt, fps), val, paid)
-        for e in path:
-            val, dt = e.run(val)
-            paid += dt
-            self.stats.edge_runs += 1
-            val = self._maybe_device(e.dst, val)
-            # cache every intermediate: cost = cumulative seconds paid to
-            # produce it in THIS ensure (what a hit on it will avoid)
-            self._insert(self._node_key(src, e.dst, fps), val, paid)
+        with spans.span("lilac.marshal") as sp:
+            if start_fmt in cached_vals:
+                # ride an already-cached intermediate (possibly built for a
+                # DIFFERENT harness) — the plan-level sharing win
+                val = self._store[cached_vals[start_fmt]]
+                self._store.move_to_end(cached_vals[start_fmt])
+                self.stats.shared_edge_hits += 1
+                ps.shared_prefix_hits += 1
+                ps.rides += 1
+                ps.shared_prefix_bytes += tree_nbytes(val)
+            else:
+                val, dt = loader.run(binding)
+                paid += dt
+                self.stats.loader_runs += 1
+                val = self._maybe_device(start_fmt, val)
+                self._insert(self._node_key(src, start_fmt, fps), val, paid)
+            for e in path:
+                val, dt = e.run(val)
+                paid += dt
+                self.stats.edge_runs += 1
+                val = self._maybe_device(e.dst, val)
+                # cache every intermediate: cost = cumulative seconds paid
+                # to produce it in THIS ensure (what a hit on it will avoid)
+                self._insert(self._node_key(src, e.dst, fps), val, paid)
+            _produced(sp, val)
         ps.build_seconds += paid
         ps.last_path = tuple(path_names)
         return val

@@ -45,6 +45,7 @@ from repro.core import harness as H
 from repro.core import plan as P
 from repro.core import plan_search as PS
 from repro.core import resilience as R
+from repro.core import spans
 from repro.core.autotune import autotune_disabled, variant_key
 from repro.core.marshal import (DataPlane, MarshalingCache, MarshalPolicy,
                                 TrackedArray)
@@ -332,7 +333,8 @@ class LilacFunction:
             key = (_signature(flat), in_tree)
             entry = self._compiled.get(key)
             if entry is None:
-                entry = self._build_entry(args, kwargs)
+                with spans.span("lilac.detect"):
+                    entry = self._build_entry(args, kwargs)
                 self._compiled[key] = entry
             self._last_compiled = (entry, in_tree, P.leaf_templates(flat))
         self.last_report = entry.report
@@ -409,7 +411,8 @@ class LilacFunction:
         self.last_report = plan.report
         self.last_selections = plan.selections
         self.last_schedules = plan.schedules
-        outs = plan.jitted(*leaves)
+        with spans.span("lilac.enqueue"):
+            outs = plan.jitted(*leaves)
         return jax.tree_util.tree_unflatten(plan.out_tree, outs)
 
     def _enabled_matches(self, entry: CompiledEntry) -> List[D.Match]:
@@ -549,6 +552,10 @@ class LilacFunction:
             pass
 
     def __call__(self, *args, **kwargs):
+        with spans.span("lilac.dispatch"):
+            return self._call(args, kwargs)
+
+    def _call(self, args, kwargs):
         flat, in_tree = jax.tree_util.tree_flatten((args, kwargs))
         # steady-state fast path: guard check -> one jitted dispatch.
         # A registry epoch moved by any (re-)registration refuses the
@@ -705,10 +712,11 @@ class LilacFunction:
             entry.joint_done = True
             return False
         try:
-            res = PS.optimize_entry(
-                flat, entry.pins, registry=self.registry, tuner=tuner,
-                platform=self.platform, mode=self.mode, cache=self.cache,
-                reuse=self.marshal_policy.reuse, width=width)
+            with spans.span("lilac.tune"):
+                res = PS.optimize_entry(
+                    flat, entry.pins, registry=self.registry, tuner=tuner,
+                    platform=self.platform, mode=self.mode, cache=self.cache,
+                    reuse=self.marshal_policy.reuse, width=width)
         except Exception:
             entry.joint_done = True     # cost model unavailable: pins stand
             return False
@@ -856,14 +864,15 @@ class LilacFunction:
                     entry, "rebake thrash (operands change per call)")
                 return
         try:
-            baked = P.bake_plan(
-                closed_jaxpr=entry.closed_jaxpr, matches=matches,
-                needed=entry.needed_for(matches), recorder=recorder,
-                raw_flat=raw_flat, flat=flat, in_tree=in_tree,
-                out_tree=entry.out_tree, report=entry.report,
-                mode=self.mode, platform=self.platform,
-                enabled=self.enabled, donate=self.donate_args,
-                registry_epoch=self.registry.epoch)
+            with spans.span("lilac.bake"):
+                baked = P.bake_plan(
+                    closed_jaxpr=entry.closed_jaxpr, matches=matches,
+                    needed=entry.needed_for(matches), recorder=recorder,
+                    raw_flat=raw_flat, flat=flat, in_tree=in_tree,
+                    out_tree=entry.out_tree, report=entry.report,
+                    mode=self.mode, platform=self.platform,
+                    enabled=self.enabled, donate=self.donate_args,
+                    registry_epoch=self.registry.epoch)
         except P.PlanDonationError:
             raise                       # user error: surface it
         except Exception as e:          # untraceable body etc: interpreter

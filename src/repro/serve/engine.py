@@ -36,6 +36,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.serve.buckets import BucketPolicy, default_buckets
 from repro.serve.metrics import ServeMetrics
 from repro.serve.scheduler import Request, Scheduler
@@ -254,19 +255,22 @@ class Engine:
 
     def step(self) -> List[Request]:
         """Admit -> re-bucket -> prefill admissions -> decode -> evict.
-        Returns the requests that finished during this step."""
-        finished: List[Request] = []
-        self._expire_deadlines()
-        admitted = self.scheduler.admissions()
-        if self.scheduler.active:
-            self._fit_buckets()
-        if admitted:
-            self._admit(admitted)
-            finished += self._evict()
-        if self.scheduler.active:
-            self._decode_once()
-            finished += self._evict()
-        return finished
+        Returns the requests that finished during this step.  Each phase
+        runs under its ``serve.*`` span (docs/serving.md)."""
+        with span("serve.step"):
+            finished: List[Request] = []
+            with span("serve.schedule"):
+                self._expire_deadlines()
+                admitted = self.scheduler.admissions()
+                if self.scheduler.active:
+                    self._fit_buckets()
+            if admitted:
+                self._admit(admitted)
+                finished += self._evict()
+            if self.scheduler.active:
+                self._decode_once()
+                finished += self._evict()
+            return finished
 
     def run_until_idle(self, max_steps: int = DEFAULT_MAX_STEPS
                        ) -> List[Request]:
@@ -341,7 +345,9 @@ class Engine:
         cache = self.model.init_cache(*shape)
         logits, caches = self._prefill(self.params, req.prompt[None, :])
         cache = self._install(cache, caches, 0, req.prompt_len, shape[1])
-        toks = [int(np.argmax(np.asarray(logits)[0]))]
+        with span("serve.readback"):
+            logits_np = np.asarray(logits)
+        toks = [int(np.argmax(logits_np[0]))]
         for B, S in shapes:
             if (B, S) != shape:
                 cache = self.model.cache_resize(cache, B=B, max_seq=S)
@@ -351,7 +357,9 @@ class Engine:
             tokens[0, 0] = toks[-1]
             pos[0] = req.prompt_len + len(toks) - 1
             logits, cache = self._decode(self.params, cache, tokens, pos)
-            toks.append(int(np.argmax(np.asarray(logits)[0])))
+            with span("serve.readback"):
+                logits_np = np.asarray(logits)
+            toks.append(int(np.argmax(logits_np[0])))
         return toks
 
     def generate_solo(self, prompt, max_new_tokens: int, *,
@@ -389,72 +397,80 @@ class Engine:
 
     def _admit(self, admitted: Sequence[Request]):
         for req in admitted:
-            slot = self.scheduler.active.index(req)
-            t0 = self.clock()
-            logits, caches = self._prefill(self.params, req.prompt[None, :])
-            self._cache = self._install(self._cache, caches, slot,
-                                        req.prompt_len, self._shape[1])
-            req.tokens.append(int(np.argmax(np.asarray(logits)[0])))
-            req.prefill_s = self.clock() - t0
-            req.ttft_s = self.clock() - req.arrival_t
-            self.metrics.record_admit(req.rid, req.prefill_s, req.ttft_s)
+            with span("serve.prefill", rid=req.rid):
+                slot = self.scheduler.active.index(req)
+                t0 = self.clock()
+                logits, caches = self._prefill(self.params,
+                                               req.prompt[None, :])
+                self._cache = self._install(self._cache, caches, slot,
+                                            req.prompt_len, self._shape[1])
+                with span("serve.readback"):
+                    logits_np = np.asarray(logits)
+                req.tokens.append(int(np.argmax(logits_np[0])))
+                req.prefill_s = self.clock() - t0
+                req.ttft_s = self.clock() - req.arrival_t
+                self.metrics.record_admit(req.rid, req.prefill_s, req.ttft_s)
 
     def _decode_once(self):
         from repro.core import faults
         tb, ts = self._shape
         active = self.scheduler.active
-        tokens = np.zeros((tb, 1), np.int32)
-        pos = np.zeros((tb,), np.int32)
-        for i, r in enumerate(active):
-            tokens[i, 0] = r.tokens[-1]
-            # the new token is written at the row's current depth
-            pos[i] = r.prompt_len + len(r.tokens) - 1
-        t0 = self.clock()
-        try:
-            if faults.ACTIVE is not None:
-                # attribute the injected fault to a rotating batch slot so
-                # chaos runs exercise eviction at every position
-                slot = faults.ACTIVE.attempts(
-                    "decode_raise", "decode") % len(active)
-                faults.fail("decode_raise", "decode", slot=slot)
-            logits, self._cache = self._decode(self.params, self._cache,
-                                               tokens, pos)
-        except Exception as e:   # containment boundary: poison one slot
-            slot = getattr(e, "slot", None)
-            if not isinstance(slot, int) or not 0 <= slot < len(active):
-                slot = len(active) - 1
-            active[slot].failed = \
-                f"decode: {type(e).__name__}: {e}"[:200]
-            self.metrics.record_decode_fault()
-            # the cache was NOT reassigned, so this step is a no-op for
-            # the survivors: they redo the identical decode next step and
-            # their streams stay bit-identical to a fault-free run
-            return
-        dt = self.clock() - t0
-        logits_np = np.asarray(logits)
-        if faults.ACTIVE is not None and np.issubdtype(
-                logits_np.dtype, np.floating):
-            if faults.check("decode_nan", "decode"):
-                slot = faults.ACTIVE.attempts(
-                    "decode_nan", "decode") % len(active)
-                logits_np = np.array(logits_np, copy=True)
-                logits_np[slot] = np.nan
-        # per-row finite check: a NaN/Inf row fails only that request; the
-        # cache row itself is overwritten or compacted away at eviction
-        finite = np.isfinite(
-            logits_np.reshape(logits_np.shape[0], -1)).all(axis=1)
-        nxt = np.argmax(logits_np, axis=-1)
-        for i, r in enumerate(active):
-            if not finite[i]:
-                r.failed = "non-finite decode logits"
+        with span("serve.decode"):
+            tokens = np.zeros((tb, 1), np.int32)
+            pos = np.zeros((tb,), np.int32)
+            for i, r in enumerate(active):
+                tokens[i, 0] = r.tokens[-1]
+                # the new token is written at the row's current depth
+                pos[i] = r.prompt_len + len(r.tokens) - 1
+            t0 = self.clock()
+            try:
+                if faults.ACTIVE is not None:
+                    # attribute the injected fault to a rotating batch slot
+                    # so chaos runs exercise eviction at every position
+                    slot = faults.ACTIVE.attempts(
+                        "decode_raise", "decode") % len(active)
+                    faults.fail("decode_raise", "decode", slot=slot)
+                logits, self._cache = self._decode(self.params, self._cache,
+                                                   tokens, pos)
+            except Exception as e:   # containment boundary: poison one slot
+                slot = getattr(e, "slot", None)
+                if not isinstance(slot, int) or not 0 <= slot < len(active):
+                    slot = len(active) - 1
+                active[slot].failed = \
+                    f"decode: {type(e).__name__}: {e}"[:200]
                 self.metrics.record_decode_fault()
-                continue
-            r.tokens.append(int(nxt[i]))
-            r.decode_shapes.append((tb, ts))
-        self.metrics.record_step(
-            dt, batch=tb, active=len(active),
-            queue_depth=self.scheduler.queue_depth,
-            bucket_hit=(tb, ts) in self._prewarmed)
+                # the cache was NOT reassigned, so this step is a no-op for
+                # the survivors: they redo the identical decode next step
+                # and their streams stay bit-identical to a fault-free run
+                return
+            dt = self.clock() - t0
+        with span("serve.readback"):
+            logits_np = np.asarray(logits)
+        with span("serve.sample"):
+            if faults.ACTIVE is not None and np.issubdtype(
+                    logits_np.dtype, np.floating):
+                if faults.check("decode_nan", "decode"):
+                    slot = faults.ACTIVE.attempts(
+                        "decode_nan", "decode") % len(active)
+                    logits_np = np.array(logits_np, copy=True)
+                    logits_np[slot] = np.nan
+            # per-row finite check: a NaN/Inf row fails only that request;
+            # the cache row itself is overwritten or compacted away at
+            # eviction
+            finite = np.isfinite(
+                logits_np.reshape(logits_np.shape[0], -1)).all(axis=1)
+            nxt = np.argmax(logits_np, axis=-1)
+            for i, r in enumerate(active):
+                if not finite[i]:
+                    r.failed = "non-finite decode logits"
+                    self.metrics.record_decode_fault()
+                    continue
+                r.tokens.append(int(nxt[i]))
+                r.decode_shapes.append((tb, ts))
+            self.metrics.record_step(
+                dt, batch=tb, active=len(active),
+                queue_depth=self.scheduler.queue_depth,
+                bucket_hit=(tb, ts) in self._prewarmed)
 
     def _expire_deadlines(self):
         """Evict requests past their per-request deadline.  Active ones
@@ -482,19 +498,20 @@ class Engine:
                                            now - r.arrival_t)
 
     def _evict(self) -> List[Request]:
-        finished, moves = self.scheduler.evict_finished()
-        for src, dst in moves:
-            self._cache = self._move(self._cache, src, dst)
-        now = self.clock()
-        for r in finished:
-            r.finish_t = now
-            if r.failed is not None:
-                self.metrics.record_fault_eviction(r.failed)
-            self.metrics.record_finish(r.rid, len(r.tokens),
-                                       now - r.arrival_t)
-            if r.failed is None and r.tokens:
-                self._maybe_shadow_request(r)
-        return finished
+        with span("serve.evict"):
+            finished, moves = self.scheduler.evict_finished()
+            for src, dst in moves:
+                self._cache = self._move(self._cache, src, dst)
+            now = self.clock()
+            for r in finished:
+                r.finish_t = now
+                if r.failed is not None:
+                    self.metrics.record_fault_eviction(r.failed)
+                self.metrics.record_finish(r.rid, len(r.tokens),
+                                           now - r.arrival_t)
+                if r.failed is None and r.tokens:
+                    self._maybe_shadow_request(r)
+            return finished
 
     def _maybe_shadow_request(self, req: Request):
         """Request-level shadow verification on a deterministic stratified

@@ -10,7 +10,10 @@ One :class:`ServeMetrics` instance per engine records
   (miss: detect/tune/bake happened while a user waited);
 * **plan / prewarm counters** — detector invocations and persistent
   plan-cache hits observed during prewarm, so a fleet operator can verify
-  the "pay detection once per fleet, not once per replica" economics.
+  the "pay detection once per fleet, not once per replica" economics;
+* **spans** — the process's ``lilac.*`` and ``serve.*`` span totals
+  (``repro.core.spans``): where the host time of set-up and of each step
+  went.
 
 ``snapshot()`` returns a JSON-able dict (``save()`` writes it) — the
 exported form the serving benchmark and any external scraper consume.
@@ -23,6 +26,8 @@ import time
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
+
+from repro.core import spans
 
 
 def percentiles(samples: Sequence[float],
@@ -200,6 +205,7 @@ class ServeMetrics:
                         "cache_resizes": self.cache_resizes},
             "resilience": self._resilience_section(),
             "prewarm": self.prewarm,
+            "spans": spans.totals(),
         }
 
     def _resilience_section(self) -> Dict[str, Any]:
