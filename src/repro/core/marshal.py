@@ -16,7 +16,7 @@ over call sequences instead of greedy local choices):
 * ``fingerprint(arr)`` — cheap content hash (full bytes below a threshold,
   strided sample + shape/dtype above it; ``exact=True`` forces full bytes).
 * ``SparseFormat`` / ``FORMATS`` — the format registry (dense, COO, CSR,
-  ELL and BCSR variants, JDS) that marshal clauses refer to by name.
+  ELL and BCSR variants, JDS, DIA) that marshal clauses refer to by name.
 * ``ConversionGraph`` / ``GRAPH`` — edges are value-level repack functions
   with measured (EWMA) costs; ``plan`` picks the cheapest path from any
   already-cached intermediate to the requested target format.
@@ -187,6 +187,8 @@ for _f in (
     SparseFormat("BCSR8x128", "block CSR, (8,128) VPU tiles"),
     SparseFormat("BCSR128x128", "block CSR, (128,128) MXU tiles"),
     SparseFormat("JDS", "jagged diagonal storage (paper Fig. 5)"),
+    SparseFormat("DIA", "diagonal storage: one row-length slab per stored "
+                        "diagonal"),
 ):
     register_format(_f)
 

@@ -13,13 +13,17 @@
 Names start with ``lilac.`` (the pass) or ``serve.`` (the engine); ids
 such as a request's go in ``stats`` (``rid=...``), never in the name, so
 spans sum by name.  ``count(name, n)`` adds to a counter kept in the same
-table (``count`` only).  With no profiler running a span costs two clock
-reads, one dict update and the annotation's inactive check.
+table (``count`` only).  ``annotate(name, **stats)`` adds stats to the
+innermost open span from code that does not hold it (a conversion inside
+the data plane's ``lilac.marshal``).  With no profiler running a span
+costs two clock reads, one dict update and the annotation's inactive
+check.
 
 The table is process-wide and never trimmed: one entry per name.
 """
 from __future__ import annotations
 
+import threading
 from time import perf_counter_ns
 from typing import Dict
 
@@ -29,6 +33,7 @@ _profiling = TraceAnnotation.is_enabled
 _SPANS: Dict[str, list] = {}        # name -> [count, total ns, max ns]
 _TRACED: Dict[str, list] = {}       # the same, of spans a profiler recorded
 _COUNTS: Dict[str, int] = {}
+_OPEN = threading.local()           # .stack: this thread's annotated spans
 
 
 def _add(table: Dict[str, list], name: str, dt: int):
@@ -58,6 +63,7 @@ class span:
                      if _profiling() else None)
         if self._ann is not None:
             self._ann.__enter__()
+            _open_stack().append(self)
         self._t0 = perf_counter_ns()
         return self
 
@@ -65,6 +71,7 @@ class span:
         dt = perf_counter_ns() - self._t0
         _add(_SPANS, self._name, dt)
         if self._ann is not None:
+            _open_stack().pop()
             self._ann.__exit__(et, ev, tb)
             _add(_TRACED, self._name, dt)
         return False
@@ -72,6 +79,21 @@ class span:
     def set(self, **stats):
         if self._ann is not None:
             self._ann.set_metadata(**stats)
+
+
+def _open_stack() -> list:
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    return stack
+
+
+def annotate(name: str, **stats):
+    """Add ``stats`` to the profiler's event of this thread's innermost
+    open span if it is named ``name``; a no-op with no profiler running."""
+    stack = getattr(_OPEN, "stack", None)
+    if stack and stack[-1]._name == name:
+        stack[-1].set(**stats)
 
 
 def count(name: str, n: int = 1):

@@ -36,6 +36,7 @@ from typing import Any, Callable, Dict, List, Optional, Union
 
 from repro.core import harness as H
 from repro.core import marshal as M
+from repro.core import spans
 from repro.core import what_lang as W
 
 
@@ -434,6 +435,23 @@ def _csr_to_bcsr128(csr):
     return csr_to_bcsr(csr, block_shape=(128, 128))
 
 
+@M.edge("CSR", "DIA", name="csr_to_dia")
+def _csr_to_dia(csr):
+    """CSR -> DIA, counted as ``lilac.dia_packed`` or ``lilac.dia_refused``
+    (the matrix has too few nonzeros per diagonal: the refusal propagates,
+    so the tuner drops the candidate), with the fill (stored values per
+    nonzero) on the enclosing ``lilac.marshal`` span."""
+    from repro.sparse.convert import DIARefused, csr_to_dia
+    try:
+        dia = csr_to_dia(csr)
+    except DIARefused:
+        spans.count("lilac.dia_refused")
+        raise
+    spans.count("lilac.dia_packed")
+    spans.annotate("lilac.marshal", fill=dia.data.size / max(csr.nnz, 1))
+    return dia
+
+
 # ---------------------------------------------------------------------------
 # Builtin repacks (single-hop fallbacks; also the graph-equivalence oracle).
 # ---------------------------------------------------------------------------
@@ -460,6 +478,11 @@ def _bcsr_pack(b: H.Binding):
 def _bcsr_pack128(b: H.Binding):
     from repro.sparse.convert import csr_to_bcsr
     return csr_to_bcsr(H._binding_to_csr(b), block_shape=(128, 128))
+
+
+@repack("dia_pack")
+def _dia_pack(b: H.Binding):
+    return _csr_to_dia(H._binding_to_csr(b))
 
 
 @repack("densify")

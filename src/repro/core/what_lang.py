@@ -864,6 +864,12 @@ HARNESS jnp.dense implements spmv_csr, spmv_coo
   host_only;
   marshal dense = densify(a, colidx, rowstr|rowidx)
       from csr_binding to DENSE;
+
+HARNESS jnp.dia implements spmv_csr, spmv_coo
+  formats CSR, COO;
+  host_only;
+  marshal dia = dia_pack(a, colidx, rowstr|rowidx) from csr_binding to DIA;
+  vjp spmv_csr_bwd(a, iv);
 """
 # delta[rowidx[j]] denotes the i==rowidx[j] indicator; the generated matcher
 # realizes it as the scatter-add-by-row skeleton (see detect.py).

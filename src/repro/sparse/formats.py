@@ -9,6 +9,8 @@ These mirror the storage formats of the paper (§3.2 Fig. 4/5):
          padded to a lane-aligned width so slabs are dense VMEM tiles)
 * BCSR — block compressed sparse row with dense (bm, bn) blocks sized for the
          MXU; the TPU-native format for the Pallas matmul kernels.
+* DIA  — diagonal storage: one row-length slab per stored diagonal, tile
+         aligned, the offsets static, so the product needs no index arrays.
 
 All containers are registered pytrees so they flow through jit/shard_map.
 Static metadata (shape, block size) lives in aux_data.
@@ -177,6 +179,37 @@ class BCSR:
 
 
 _register(BCSR, ("blocks", "block_col", "block_rowptr"), ("shape", "block_shape"))
+
+
+#: DIA pads each slab to a multiple of this many rows: one (8, 128) float32
+#: tile of the TPU, so a slab is whole tiles that a product reads in place.
+DIA_ROW_ALIGN = 1024
+
+
+@dataclasses.dataclass(frozen=True)
+class DIA:
+    """Diagonal storage: slab d holds ``A[i, i + offsets[d]]`` at row i,
+    zero where ``i + offsets[d]`` falls outside the columns.
+
+    data:    (ndiag, padded // 128, 128) — one slab per stored diagonal,
+             its rows padded with zeros to ``padded``, a multiple of
+             ``DIA_ROW_ALIGN``.  A plain (ndiag, rows) array would put
+             eight slabs in each (8, 128) tile, and XLA re-lays every slab
+             out before a product can use it.
+    offsets: ``col - row`` of each stored diagonal, ascending (static)
+    """
+
+    data: jax.Array
+    offsets: Tuple[int, ...]
+    shape: Tuple[int, int]
+
+    @property
+    def slabs(self) -> jax.Array:
+        """The (ndiag, rows) view: ``slabs[d, i] = A[i, i + offsets[d]]``."""
+        return self.data.reshape(self.data.shape[0], -1)[:, :self.shape[0]]
+
+
+_register(DIA, ("data",), ("offsets", "shape"))
 
 
 # ---------------------------------------------------------------------------

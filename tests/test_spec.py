@@ -453,6 +453,7 @@ _EXPECTED = {
         ("jnp.ell", ("cpu", "tpu"), ("CSR", "COO"), False),
         ("jnp.bcsr", ("cpu", "tpu"), ("CSR", "COO"), False),
         ("jnp.dense", ("cpu", "tpu"), ("CSR", "COO"), False),
+        ("jnp.dia", ("cpu", "tpu"), ("CSR", "COO"), False),
         ("pallas.ell", ("tpu",), ("CSR", "COO"), False),
         ("pallas.bcsr", ("tpu",), ("CSR", "COO"), False),
     ],

@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles of the three Pallas kernels for a TPU v5e.
+"""Ahead-of-time compiles of the three Pallas kernels, and of the XLA
+product over diagonal storage, for a TPU v5e.
 
 Interpret mode (the rest of the suite) checks results, not what Mosaic
 accepts: block shapes, in-kernel gathers and the VMEM budget only fail
@@ -6,7 +7,8 @@ when the kernel is lowered for a real chip.  These tests lower and compile
 each kernel against a *described* ``v5e:2x2`` topology, at the shapes the
 one-chip smoke (``chip_smoke.py``) runs, without a chip attached.  Each
 asserts that the compiled program holds the Mosaic kernel
-(``tpu_custom_call``).
+(``tpu_custom_call``); the DIA product, that it holds no gather, no
+matmul and no bfloat16, and reads its slabs once.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library, and every test worker imports
@@ -116,3 +118,34 @@ def test_gmm_compiles(one_chip, dimsem, D, F):
                       ((Tp, D), jnp.bfloat16),
                       ((E, D, F), jnp.bfloat16),
                       ((Tp // tm,), jnp.int32))
+
+
+def test_dia_spmv_compiles(one_chip):
+    """HPCG-104's 27-point stencil in diagonal storage (n = 104^3, 27
+    diagonals): shifted slices of x and elementwise multiply-adds only."""
+    import itertools
+
+    from repro.core.harness import _spmv_dia_host
+    from repro.sparse.formats import DIA, DIA_ROW_ALIGN
+    nx = 104
+    n = nx ** 3
+    offsets = tuple(sorted(dz * nx * nx + dy * nx + dx for dz, dy, dx
+                           in itertools.product((-1, 0, 1), repeat=3)))
+
+    padded = -(-n // DIA_ROW_ALIGN) * DIA_ROW_ALIGN
+
+    def fn(data, x):
+        return _spmv_dia_host({"iv": x}, None,
+                              dia=DIA(data, offsets, (n, n)))
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((27, padded // 128, 128), (n,))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    for op in ("tpu_custom_call", " gather(", " dot(", " convolution(",
+               "bf16["):
+        assert op not in text, op
+    # the slabs are read in place, once: no relayout pass before the
+    # multiply-adds (a (27, n) array cost 374 MB a call this way)
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 1.1 * (27 * padded + 3 * n) * 4
