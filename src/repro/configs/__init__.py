@@ -15,4 +15,5 @@ from repro.configs import (  # noqa: F401
     olmo_1b,
     jamba_v0_1_52b,
     hubert_xlarge,
+    granite_4_0_h_small,
 )

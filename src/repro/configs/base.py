@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -24,10 +24,36 @@ class ArchConfig:
     causal: bool = True
     frontend: str = "none"        # none | stub  (stub: precomputed embeds)
     rope_theta: float = 1e4
+    rope: bool = True             # False: no positional encoding (NoPE)
+    norm_eps: float = 1e-6
+    # Granite's scalars: embeddings x embedding_multiplier, each residual
+    # branch x residual_multiplier, logits / logits_scaling, attention
+    # softmax scale attention_multiplier (None: 1/sqrt(head_dim))
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
+    tie_embeddings: bool = False  # the head is the embedding's transpose
     d_state: int = 16             # mamba state width
     attn_layer_period: int = 0    # jamba: 8
     attn_layer_offset: int = 4
     moe_layer_period: int = 0     # jamba: 2
+    # the mixer of each layer of one period, when the pattern is neither
+    # homogeneous nor jamba's ("attn" | "mamba2"); every layer then has
+    # an MoE (granite-4.0-h: 9 mamba2 + 1 attn per period of 10)
+    layer_types: Tuple[str, ...] = ()
+    # Mamba-2 mixer: ssm_heads heads of ssm_head_dim channels (d_inner =
+    # their product), B and C shared by the heads of each of ssm_groups
+    # groups, chunked (SSD) prefill in chunks of ssm_chunk tokens
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
+    # one shared SwiGLU expert beside the routed ones (0: none)
+    shared_d_ff: int = 0
+    # (first, count) of the routed experts this chip holds; the router
+    # keeps all moe_experts outputs.  None: every expert is held
+    experts_held: Optional[Tuple[int, int]] = None
     moe_impl: str = "grouped"     # naive | lilac | grouped
     # MoE formulation used on the one-token decode path.  "grouped_flat"
     # (default) is the hand-written scatter dispatch; "naive_flat" emits
@@ -117,8 +143,14 @@ def shape_skips(cfg: ArchConfig, shape: ShapeConfig) -> Optional[str]:
 def smoke_config(cfg: ArchConfig) -> ArchConfig:
     """Reduced same-family config for CPU smoke tests."""
     period = cfg.attn_layer_period or 1
+    if cfg.layer_types:             # one whole period of the listed kinds
+        cfg = cfg.replace(
+            ssm_heads=8, ssm_head_dim=16, d_state=16, ssm_chunk=8,
+            shared_d_ff=32 if cfg.shared_d_ff else 0,
+            experts_held=(2, 4) if cfg.experts_held else None)
     return cfg.replace(
-        n_layers=2 * period if period > 1 else 2,
+        n_layers=(len(cfg.layer_types) if cfg.layer_types
+                  else 2 * period if period > 1 else 2),
         d_model=64,
         n_heads=4,
         n_kv_heads=min(cfg.n_kv_heads, 4) if cfg.n_kv_heads > 1 else 1,

@@ -455,10 +455,14 @@ def _moe_capacity(b: Binding, ctx: CallCtx, capacity_factor: float = 2.0):
     flat_e = idx.reshape(-1)                                    # (T*K,)
     flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)      # (T*K,)
     flat_g = gate.reshape(-1)
+    # a pair routed to an expert outside [0, E) (held elsewhere) takes no
+    # slot and adds nothing
+    held = (flat_e >= 0) & (flat_e < E)
+    flat_e = jnp.where(held, flat_e, 0)
     # position of each routed pair within its expert queue
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)         # (TK, E)
+    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32) * held[:, None]
     pos = (jnp.cumsum(onehot, axis=0) - onehot)[jnp.arange(T * K), flat_e]
-    keep = pos < C
+    keep = held & (pos < C)
     slot = jnp.where(keep, flat_e * C + pos, E * C)             # overflow -> drop
     # gather tokens into (E*C+1, D) buckets
     xb = jnp.zeros((E * C + 1, x.shape[1]), x.dtype).at[slot].set(x[flat_t])

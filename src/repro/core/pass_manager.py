@@ -601,12 +601,20 @@ class LilacFunction:
         recorder = (P.PlanRecorder()
                     if self.bake_enabled and not entry.no_bake
                     else None)
+        # A donated signature records its first call under an abstract
+        # trace of the donated operands (see below); marshaling then runs
+        # outside the shared cache, so no traced value lands in it.
+        abstract = (recorder is not None and bool(self.donate_args)
+                    and self.policy != "autotune"
+                    and not any(isinstance(x, jax.core.Tracer)
+                                for x in uflat))
 
         def ctx_factory(m):
             ctx = self._ctx_factory(m)
             if recorder is not None:
-                ctx.cache = P.recording_cache(ctx.cache,
-                                              recorder.slot(m).buffers)
+                ctx.cache = P.recording_cache(
+                    None if abstract else ctx.cache,
+                    recorder.slot(m).buffers)
             return ctx
 
         selections: List[Tuple[D.Match, str]] = []
@@ -650,6 +658,41 @@ class LilacFunction:
         contain = R.Containment(self.registry, R.shared_quarantine(),
                                 on_quarantine=on_quarantine,
                                 stats=self.resilience_stats)
+        if abstract:
+            # Evaluated eagerly, the interpreter would hold the donated
+            # operands and their replacements at once (a serving cache
+            # twice over).  So the first call of a donated signature
+            # records the rewrite with the donated operands abstract, bakes,
+            # and serves the baked plan, which consumes them in place.  The
+            # recording runs without containment (a harness that fails on
+            # an abstract operand is no incident); a failure, a marshaled
+            # buffer or a plan that did not bake falls back to the eager
+            # call below.
+            def record(vals):
+                return run_rewritten(
+                    entry.closed_jaxpr, matches, select, vals, ctx_factory,
+                    on_select=on_select, needed=entry.needed_for(matches))
+
+            if self._record_abstract(record, uflat, recorder):
+                self.last_selections = selections
+                self.last_schedules = schedules
+                self._maybe_persist(entry)
+                self._maybe_bake(entry, matches, recorder, raw_flat, uflat,
+                                 in_tree)
+                plan = entry.plan
+                leaves = (plan.match_and_unwrap(in_tree, raw_flat,
+                                                self.enabled)
+                          if plan is not None else None)
+                if leaves is not None:
+                    self._last_plan = plan
+                    self._note_hot(plan)
+                    return self._serve_plan(plan, leaves, in_tree)
+            abstract = False
+            recorder = (P.PlanRecorder()
+                        if self.bake_enabled and not entry.no_bake
+                        else None)
+            selections.clear()
+            schedules.clear()
         # containment retry loop: a ReferenceFallback disables ONE match
         # (its anchor then evaluates as a plain equation), so the loop is
         # bounded by the match count + the final all-reference pass
@@ -681,6 +724,27 @@ class LilacFunction:
         return jax.tree_util.tree_unflatten(entry.out_tree, outs)
 
     # -- plan lifecycle ------------------------------------------------------
+
+    def _record_abstract(self, run, uflat, recorder: P.PlanRecorder) -> bool:
+        """``run`` the rewrite once with the donated operands abstract
+        (the rest concrete), for its recording alone.  True when it ran
+        and recorded no marshaled buffer (baking checks the rest)."""
+        don = sorted(i for i in set(self.donate_args)
+                     if 0 <= i < len(uflat))
+
+        def record(*dv):
+            vals = list(uflat)
+            for i, v in zip(don, dv):
+                vals[i] = v
+            return run(vals)
+
+        try:
+            jax.eval_shape(record, *(
+                jax.ShapeDtypeStruct(uflat[i].shape, uflat[i].dtype)
+                for i in don))
+        except Exception:
+            return False
+        return not any(s.buffers for s in recorder.slots.values())
 
     def _maybe_joint(self, entry: CompiledEntry) -> bool:
         """Run the joint whole-program plan search once per entry, after
@@ -1015,7 +1079,10 @@ class CompileOptions:
     ``donate_args``  flat argument positions donated to the baked plan's
                   XLA executable (output may alias their buffers).  Only
                   donate operands you never reuse after the call; positions
-                  feeding marshaled operands are rejected.
+                  feeding marshaled operands are rejected.  Outside the
+                  ``autotune`` policy a signature's first call is recorded
+                  with them abstract and served by the baked plan, so it
+                  consumes them too and never holds them twice.
     ``registry``/``detector``/``cache``  dependency injection for tests
                   and benchmarks; None picks the global instances.  Pass
                   the same DataPlane as ``cache`` to several compiled

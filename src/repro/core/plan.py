@@ -752,8 +752,6 @@ def bake_plan(*, closed_jaxpr, matches, needed, recorder: PlanRecorder,
     ``flat`` the unwrapped ones the trace runs on.  Raises
     :class:`PlanBakeError` (or whatever the trace raises) on failure; the
     caller decides whether to disable baking for the entry."""
-    import jax.numpy as jnp
-
     from repro.core import faults
     from repro.core.harness import CallCtx
     from repro.core.rewrite import run_rewritten
@@ -815,9 +813,9 @@ def bake_plan(*, closed_jaxpr, matches, needed, recorder: PlanRecorder,
         return run_rewritten(closed_jaxpr, matches, select, list(leaves),
                              ctx_factory, needed=needed)
 
-    jitted = functools.partial(
-        jax.jit(baked, donate_argnums=tuple(i + 1 for i in donate)),
-        tuple(buf_flat[i] for i in dyn))
+    jit_baked = jax.jit(baked, donate_argnums=tuple(i + 1 for i in donate))
+    buf_args = tuple(buf_flat[i] for i in dyn)
+    jitted = functools.partial(jit_baked, buf_args)
     traced = any(isinstance(x, jax.core.Tracer) for x in flat)
     if traced:
         # Baking under a transform trace (the call that resolved the
@@ -833,13 +831,14 @@ def bake_plan(*, closed_jaxpr, matches, needed, recorder: PlanRecorder,
     else:
         # Warm-up compile now, so the first fast-path call is already
         # fast — and so an untraceable body fails HERE (the caller falls
-        # back to the interpreter) rather than on a later dispatch.
-        # Donated positions get copies: the caller's buffers must survive
-        # the warm-up.
-        warm = list(flat)
-        for i in donate:
-            warm[i] = jnp.array(warm[i])
-        jax.block_until_ready(jitted(*warm))
+        # back to the interpreter) rather than on a later dispatch.  With
+        # donated positions it compiles without running: the caller's
+        # buffers must survive, and copies of them (a serving cache) may
+        # not fit beside the originals.
+        if donate:
+            jit_baked.lower(buf_args, *flat).compile()
+        else:
+            jax.block_until_ready(jitted(*flat))
 
     guards = [_Guard(pos, raw_flat[pos]) for pos in sorted(guard_positions)]
     # Closure captures: jax keeps a numpy capture in consts as a live

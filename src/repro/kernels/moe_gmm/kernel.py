@@ -11,7 +11,9 @@ FLOPs: sum_e ceil(c_e/tm)*tm * D * F  ~=  T*K*D*F  (vs naive E*T*D*F) —
 exact results, no token drops (unlike capacity-factor dispatch).
 
 Grid: (m_tiles, n_tiles, k_tiles), k fastest -> f32 accumulation in the
-output VMEM block across k steps (revisiting pattern).
+output VMEM block across k steps (revisiting pattern).  m_tiles may be
+given at run time (the row tiles that hold rows), so tiles past the rows
+held are never stepped.
 
 Schedule parameters (``tune`` clauses in the HARNESS block): ``tm``
 (token-tile rows — also the group-alignment quantum), ``fn``/``dk``
@@ -53,14 +55,21 @@ def _gmm_kernel(tile_expert_ref, xs_ref, w_ref, out_ref):
 def gmm_pallas(xs: jax.Array,           # (Tp, D) group-aligned rows
                w: jax.Array,            # (E, D, F)
                tile_expert: jax.Array,  # (Tp//tm,) int32
+               live_tiles: Optional[jax.Array] = None,   # (1,) int32
                tm: int = 128, fn: int = 128, dk: int = 128,
                dimension_semantics: Optional[Tuple[str, ...]] = None,
                interpret: bool = False) -> jax.Array:
+    """Only the leading ``live_tiles`` row tiles (None: all of them) hold
+    rows: the grid's row dimension is that count, read at run time, so the
+    steps follow the rows held and not the buffer's worst-case size.  The
+    output rows past them are left unwritten and must not be read."""
     Tp, D = xs.shape
     E, D2, F = w.shape
     assert D == D2 and Tp % tm == 0 and D % dk == 0 and F % fn == 0, \
         (xs.shape, w.shape, (tm, dk, fn))
-    grid = (Tp // tm, F // fn, D // dk)
+    rows = (Tp // tm if live_tiles is None
+            else live_tiles.astype(jnp.int32)[0])
+    grid = (rows, F // fn, D // dk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,

@@ -18,29 +18,37 @@ from repro.kernels.moe_gmm.ref import moe_ffn_ref
 def _route(idx: jax.Array, T: int, K: int, E: int, tm: int):
     """Sort (token, k) pairs by expert and compute group-aligned row slots.
 
-    Returns (dest, tile_expert, Tp):
+    A pair whose expert id lies outside [0, E) (an expert another chip
+    holds) takes no row: its ``dest`` is ``Tp``, one past the buffer, so
+    a scatter with ``mode="drop"`` skips it and a gather must mask it.
+
+    Returns (dest, tile_expert, live_tiles, Tp):
       dest:        (T*K,) destination row of each flat pair in the aligned
                    buffer (rows grouped by expert, groups padded to tm)
       tile_expert: (Tp//tm,) expert id of every row tile
+      live_tiles:  (1,) how many leading tiles hold rows; the kernel's
+                   grid covers these alone, so its steps follow the pairs
+                   held, not T*K
     """
     TK = T * K
     Tp = int(np.ceil(TK / tm) * tm + (E - 1) * tm)  # worst-case alignment pad
     flat_e = idx.reshape(-1)
-    counts = jnp.bincount(flat_e, length=E)                       # (E,)
+    held = (flat_e >= 0) & (flat_e < E)
+    e = jnp.where(held, flat_e, 0)
+    onehot = jax.nn.one_hot(e, E, dtype=jnp.int32) * held[:, None]  # (TK, E)
+    counts = jnp.sum(onehot, axis=0)                              # (E,)
     aligned = ((counts + tm - 1) // tm) * tm
-    aligned = jnp.where(counts == 0, 0, aligned)
-    group_start = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(aligned)[:-1].astype(jnp.int32)])
+    bounds = jnp.cumsum(aligned).astype(jnp.int32)                # (E,)
+    group_start = bounds - aligned
     # rank of each pair within its expert
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)           # (TK, E)
-    rank = (jnp.cumsum(onehot, axis=0) - onehot)[jnp.arange(TK), flat_e]
-    dest = group_start[flat_e] + rank                             # (TK,)
+    rank = (jnp.cumsum(onehot, axis=0) - onehot)[jnp.arange(TK), e]
+    dest = jnp.where(held, group_start[e] + rank, Tp)             # (TK,)
     # expert of each row tile: search the group boundary table
-    bounds = jnp.cumsum(aligned)                                  # (E,)
     tile_rows = jnp.arange(Tp // tm, dtype=jnp.int32) * tm
     tile_expert = jnp.searchsorted(bounds, tile_rows, side="right").astype(jnp.int32)
     tile_expert = jnp.minimum(tile_expert, E - 1)
-    return dest, tile_expert, Tp
+    live_tiles = (bounds[-1] // tm).reshape(1)
+    return dest, tile_expert, live_tiles, Tp
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "fn", "dk",
@@ -64,24 +72,27 @@ def moe_ffn(x: jax.Array,      # (T, D)
     K = idx.shape[1]
     E = wg.shape[0]
     F = wg.shape[2]
-    dest, tile_expert, Tp = _route(idx, T, K, E, tm)
+    dest, tile_expert, live, Tp = _route(idx, T, K, E, tm)
     flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)
-    xs = jnp.zeros((Tp, D), x.dtype).at[dest].set(x[flat_t])
+    xs = jnp.zeros((Tp, D), x.dtype).at[dest].set(x[flat_t], mode="drop")
     dims = ((dimension_semantics, dimension_semantics, "arbitrary")
             if dimension_semantics else None)
     dk_d, fn_f = _tile(D, dk), _tile(F, fn)   # contract D / output F (up)
     dk_f, fn_d = _tile(F, dk), _tile(D, fn)   # contract F / output D (down)
     # each matmul accumulates in f32 and rounds to the activation dtype,
     # the rounding points of the naive dense-dispatch program
-    g = gmm_pallas(xs, wg, tile_expert, tm=tm, fn=fn_f, dk=dk_d,
+    g = gmm_pallas(xs, wg, tile_expert, live, tm=tm, fn=fn_f, dk=dk_d,
                    dimension_semantics=dims, interpret=interpret)
-    u = gmm_pallas(xs, wu, tile_expert, tm=tm, fn=fn_f, dk=dk_d,
+    u = gmm_pallas(xs, wu, tile_expert, live, tm=tm, fn=fn_f, dk=dk_d,
                    dimension_semantics=dims, interpret=interpret)
     h = jax.nn.silu(g.astype(x.dtype)) * u.astype(x.dtype)
-    y = gmm_pallas(h, wd, tile_expert, tm=tm, fn=fn_d, dk=dk_f,
+    y = gmm_pallas(h, wd, tile_expert, live, tm=tm, fn=fn_d, dk=dk_f,
                    dimension_semantics=dims, interpret=interpret)  # (Tp, D)
     flat_g = gate.reshape(-1).astype(x.dtype).astype(jnp.float32)
-    contrib = y.astype(x.dtype).astype(jnp.float32)[dest] * flat_g[:, None]
+    # an absent pair reads no row: the rows past the live tiles are never
+    # written, so a clamped gather of them is masked, not multiplied by 0
+    rows = y.astype(x.dtype).astype(jnp.float32)[jnp.minimum(dest, Tp - 1)]
+    contrib = jnp.where((dest < Tp)[:, None], rows, 0.0) * flat_g[:, None]
     out = jax.ops.segment_sum(contrib, flat_t, num_segments=T)
     return out.astype(x.dtype)
 

@@ -6,7 +6,7 @@ matching *_spec functions (see spec.py).
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -108,26 +108,28 @@ def attention_spec(d_model: int, n_heads: int, n_kv: int,
     }
 
 
-def _qkv(p, x, positions, theta):
+def _qkv(p, x, positions, theta, use_rope: bool = True):
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    q = rope(q, positions, theta)
-    k = rope(k, positions, theta)
+    if use_rope:
+        q = rope(q, positions, theta)
+        k = rope(k, positions, theta)
     return q, k, v
 
 
 def chunked_attention(q, k, v, *, causal: bool, kv_chunk: int = 1024,
-                      q_positions=None, kv_positions=None):
+                      q_positions=None, kv_positions=None, scale=None):
     """Memory-efficient attention: scan over kv chunks with running
     (max, denom, acc) — O(S * kv_chunk) live logits instead of O(S^2).
 
-    q: (B, Sq, H, dh); k, v: (B, Skv, KV, dh) with H % KV == 0.
+    q: (B, Sq, H, dh); k, v: (B, Skv, KV, dh) with H % KV == 0.  ``scale``
+    is the softmax scale (None: 1/sqrt(dh)).
     """
     B, Sq, H, dh = q.shape
     Skv, KV = k.shape[1], k.shape[2]
     G = H // KV
-    scale = 1.0 / np.sqrt(dh)
+    scale = 1.0 / np.sqrt(dh) if scale is None else scale
     if q_positions is None:
         q_positions = jnp.arange(Sq)
     if kv_positions is None:
@@ -179,7 +181,8 @@ def attention_block(p, x, *, positions, causal: bool, theta: float,
 
 
 def attention_decode_stacked(p, x, k_cache, v_cache, pos, *,
-                             theta: float):
+                             theta: float, use_rope: bool = True,
+                             scale=None):
     """One-token decode against a PER-LAYER (B, S, KV, dh) cache buffer.
 
     The new k/v token is written with a tiny dynamic_update_slice directly
@@ -192,7 +195,9 @@ def attention_decode_stacked(p, x, k_cache, v_cache, pos, *,
     ``pos`` is a scalar (whole batch at one position — the classic path)
     or a (B,) vector of per-row positions (continuous batching: every slot
     is at a different depth).  The scalar path is left byte-for-byte
-    unchanged so existing baked plans keep matching.
+    unchanged so existing baked plans keep matching.  ``use_rope`` False
+    leaves positions out of q and k (NoPE); ``scale`` is the softmax scale
+    (None: 1/sqrt(dh)).
     """
     B = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
@@ -201,8 +206,9 @@ def attention_decode_stacked(p, x, k_cache, v_cache, pos, *,
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    q = rope(q, positions, theta)
-    k = rope(k, positions, theta)
+    if use_rope:
+        q = rope(q, positions, theta)
+        k = rope(k, positions, theta)
     if per_slot:
         write = jax.vmap(
             lambda c, t, pp: jax.lax.dynamic_update_slice(c, t, (pp, 0, 0)))
@@ -219,7 +225,9 @@ def attention_decode_stacked(p, x, k_cache, v_cache, pos, *,
     G = H // KV
     qg = q.reshape(B, 1, KV, G, -1)
     logits = jnp.einsum("bskgd,bckd->bskgc", qg.astype(F32),
-                        ck.astype(F32)) / np.sqrt(q.shape[-1])
+                        ck.astype(F32))
+    logits = (logits / np.sqrt(q.shape[-1]) if scale is None
+              else logits * scale)
     if per_slot:
         mask = (jnp.arange(Smax)[None, None, None, None, :]
                 <= pos[:, None, None, None, None])
@@ -283,22 +291,33 @@ def mlp_block(p, x):
 # MoE: naive (dense dispatch), lilac (detected+rewritten), grouped (direct)
 # ---------------------------------------------------------------------------
 
-def moe_spec(d_model: int, d_ff: int, n_experts: int) -> Dict[str, ParamSpec]:
+def moe_spec(d_model: int, d_ff: int, n_experts: int,
+             n_held: int = 0) -> Dict[str, ParamSpec]:
+    """The router scores all ``n_experts``; weights are held for
+    ``n_held`` of them (0: all)."""
+    e = n_held or n_experts
     return {
         "router": ParamSpec((d_model, n_experts), ("embed", "expert"),
                             dtype=jnp.float32),
-        "wg": ParamSpec((n_experts, d_model, d_ff), ("expert", "embed", "mlp")),
-        "wu": ParamSpec((n_experts, d_model, d_ff), ("expert", "embed", "mlp")),
-        "wd": ParamSpec((n_experts, d_ff, d_model), ("expert", "mlp", "embed")),
+        "wg": ParamSpec((e, d_model, d_ff), ("expert", "embed", "mlp")),
+        "wu": ParamSpec((e, d_model, d_ff), ("expert", "embed", "mlp")),
+        "wd": ParamSpec((e, d_ff, d_model), ("expert", "mlp", "embed")),
     }
 
 
-def moe_router(p, x, topk: int):
-    """returns (gate (B,S,K) f32 normalized, idx (B,S,K) int32, aux_loss)."""
+def moe_router(p, x, topk: int, aux_loss: bool = True):
+    """returns (gate (B,S,K) f32 normalized, idx (B,S,K) int32, aux_loss).
+    Softmax over all experts, then the top-k renormalised: the same gates
+    as a softmax over the top-k logits alone (Granite's, Mixtral's), since
+    the softmax's denominator cancels.  ``aux_loss`` False (a decode
+    step, which trains nothing) returns 0 for it and leaves its
+    reduction out of the program."""
     logits = jnp.einsum("bsd,de->bse", x.astype(F32), p["router"])
     probs = jax.nn.softmax(logits, axis=-1)
     gate, idx = jax.lax.top_k(probs, topk)
     gate = gate / jnp.maximum(jnp.sum(gate, axis=-1, keepdims=True), 1e-9)
+    if not aux_loss:
+        return gate, idx.astype(jnp.int32), jnp.zeros((), F32)
     # load-balancing auxiliary loss (Switch-style)
     E = p["router"].shape[-1]
     me = jnp.mean(probs, axis=(0, 1))                       # (E,)
@@ -477,16 +496,29 @@ def _moe_grouped_shardmap(x, gate, idx, wg, wu, wd, *,
 
 
 def moe_block(p, x, *, topk: int, impl: str = "grouped",
-              capacity_factor: float = 2.0, shard_ctx=None):
+              capacity_factor: float = 2.0, shard_ctx=None,
+              held_first: Optional[int] = None, aux_loss: bool = True):
     """x: (B, S, D). Groups = sequences (train/prefill) — dispatch is
     per-sequence so no cross-batch communication is needed to form buckets;
     decode callers pass S=1 groups of the whole batch instead.
 
     shard_ctx: None (single host) or dict(batch_axes, model_axis,
-    model_size) — selects the shard_map EP path."""
+    model_size) — selects the shard_map EP path.
+
+    held_first: the layer holds experts [held_first, held_first + E) of
+    the router's experts (E = the weights' leading dim).  Routing is over
+    all of them; ids are shifted into the held range, so an absent
+    expert's id lies outside [0, E) and its pair adds nothing.  The result
+    is this share's part of the layer (expert parallelism without its
+    exchange)."""
     B, S, D = x.shape
-    gate, idx, aux = moe_router(p, x, topk)
+    gate, idx, aux = moe_router(p, x, topk, aux_loss)
     wg, wu, wd = p["wg"], p["wu"], p["wd"]
+    if held_first is not None:
+        if impl not in ("naive", "naive_flat", "lilac"):
+            raise ValueError(f"a held share of experts needs a dispatch "
+                             f"that drops absent ids; {impl!r} does not")
+        idx = idx - held_first
     if impl == "naive":
         fn = functools.partial(_moe_naive_2d, wg=wg, wu=wu, wd=wd)
         out = jax.vmap(lambda xx, gg, ii: fn(xx, gg, ii))(x, gate, idx)

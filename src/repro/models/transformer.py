@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.models import layers as L
 from repro.models import mamba as M
+from repro.models import mamba2 as M2
 from repro.models import rwkv as R
 from repro.models.spec import ParamSpec
 
@@ -30,6 +31,9 @@ F32 = jnp.float32
 
 def arch_pattern(cfg) -> List[Tuple[str, str]]:
     """[(mixer_kind, ffn_kind)] per layer within one period."""
+    if cfg.layer_types:                           # granite-4.0-h: MoE on all
+        ffn = "moe" if cfg.moe_experts else "mlp"
+        return [(mixer, ffn) for mixer in cfg.layer_types]
     if cfg.family == "ssm":                       # rwkv6
         return [("rwkv", "channelmix")]
     if cfg.family == "hybrid":                    # jamba: attn @ idx 4 of 8
@@ -68,6 +72,8 @@ def block_spec(cfg, mixer: str, ffn: str) -> Dict[str, Any]:
         spec["attn"] = L.attention_spec(d, cfg.n_heads, cfg.n_kv_heads, hd)
     elif mixer == "mamba":
         spec["mamba"] = M.mamba_spec(d, d_state=cfg.d_state)
+    elif mixer == "mamba2":
+        spec["mamba2"] = M2.mamba2_spec(cfg)
     elif mixer == "rwkv":
         spec["tm"] = R.timemix_spec(d, cfg.n_heads)
     else:
@@ -76,7 +82,10 @@ def block_spec(cfg, mixer: str, ffn: str) -> Dict[str, Any]:
     if ffn == "mlp":
         spec["mlp"] = L.mlp_spec(d, cfg.d_ff)
     elif ffn == "moe":
-        spec["moe"] = L.moe_spec(d, cfg.d_ff, cfg.moe_experts)
+        spec["moe"] = L.moe_spec(d, cfg.d_ff, cfg.moe_experts,
+                                 (cfg.experts_held or (0, 0))[1])
+        if cfg.shared_d_ff:
+            spec["shared"] = L.mlp_spec(d, cfg.shared_d_ff)
     elif ffn == "channelmix":
         spec["cm"] = R.channelmix_spec(d, cfg.d_ff)
     else:
@@ -99,8 +108,9 @@ def model_spec(cfg) -> Dict[str, Any]:
     spec: Dict[str, Any] = {
         "blocks": _stack_spec(period_spec, n_periods(cfg)),
         "final_norm": _norm_spec(cfg),
-        "unembed": ParamSpec((d, cfg.vocab), ("embed", "vocab")),
     }
+    if not cfg.tie_embeddings:
+        spec["unembed"] = ParamSpec((d, cfg.vocab), ("embed", "vocab"))
     if cfg.frontend == "none":
         spec["embed"] = ParamSpec((cfg.vocab, d), ("vocab", "embed"))
     # stub frontends feed precomputed embeddings; no embed table needed for
@@ -115,8 +125,68 @@ def model_spec(cfg) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 def _norm_apply(cfg, p, x):
+    if cfg.norm == "rmsnorm":
+        return L.rmsnorm(p, x, cfg.norm_eps)
     _, fn = L.make_norm(cfg.norm, cfg.d_model)
     return fn(p, x)
+
+
+def _residual(cfg, x, branch):
+    """x + branch, the branch scaled by Granite's residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return x + branch
+
+
+def _embed(cfg, params, tokens):
+    embed = _constrain_leaf(
+        cfg, ParamSpec((cfg.vocab, cfg.d_model), ("vocab", "embed")),
+        params["embed"])
+    x = embed[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _logits(cfg, params, x):
+    """Float32 logits of x (..., D): the unembedding, or the embedding's
+    transpose when tied, over Granite's logits scaling."""
+    if cfg.tie_embeddings:
+        w = _constrain_leaf(
+            cfg, ParamSpec((cfg.vocab, cfg.d_model), ("vocab", "embed")),
+            params["embed"])
+        logits = jnp.einsum("...d,vd->...v", x.astype(F32), w.astype(F32))
+    else:
+        w = _constrain_leaf(
+            cfg, ParamSpec((cfg.d_model, cfg.vocab), ("embed", "vocab")),
+            params["unembed"])
+        logits = jnp.einsum("...d,dv->...v", x.astype(F32), w.astype(F32))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
+
+
+def _ffn(cfg, bp, x, ffn: str, moe_impl: str, shard_ctx=None,
+         aux_loss: bool = True):
+    """The feed-forward half of a block on normed x: (out, aux, extra
+    cache entries)."""
+    aux = jnp.zeros((), F32)
+    if ffn == "mlp":
+        return L.mlp_block(bp["mlp"], x), aux, {}
+    if ffn == "moe":
+        held = cfg.experts_held[0] if cfg.experts_held else None
+        out, aux = L.moe_block(bp["moe"], x, topk=cfg.moe_topk,
+                               impl=moe_impl,
+                               capacity_factor=cfg.capacity_factor,
+                               shard_ctx=shard_ctx, held_first=held,
+                               aux_loss=aux_loss)
+        if "shared" in bp:
+            out = out + L.mlp_block(bp["shared"], x)
+        return out, aux, {}
+    if ffn == "channelmix":
+        out, last_cm = R.channelmix(bp["cm"], x)
+        return out, aux, {"last_cm": last_cm}
+    raise ValueError(ffn)
 
 
 def _axis_sizes(cfg) -> Dict[str, int]:
@@ -209,11 +279,13 @@ def apply_block(cfg, bp, x, *, mixer: str, ffn: str, positions,
     cache_entry = {}
     h = _norm_apply(cfg, bp["ln1"], x)
     if mixer == "attn":
-        q, k, v = L._qkv(bp["attn"], h, positions, cfg.rope_theta)
+        q, k, v = L._qkv(bp["attn"], h, positions, cfg.rope_theta,
+                         use_rope=cfg.rope)
         out = L.chunked_attention(
             q, k, v, causal=cfg.causal, kv_chunk=cfg.kv_chunk,
             q_positions=positions[0] if positions.ndim > 1 else positions,
-            kv_positions=positions[0] if positions.ndim > 1 else positions)
+            kv_positions=positions[0] if positions.ndim > 1 else positions,
+            scale=cfg.attention_multiplier)
         out = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype),
                          bp["attn"]["wo"])
         cache_entry = {"k": k.astype(x.dtype), "v": v.astype(x.dtype)}
@@ -224,6 +296,10 @@ def apply_block(cfg, bp, x, *, mixer: str, ffn: str, positions,
                  jnp.zeros((B, M.CONV_K - 1, di), F32))
         out, state = M.mamba_block(bp["mamba"], h, state, cfg.d_state)
         cache_entry = {"ssm": state[0], "conv": state[1]}
+    elif mixer == "mamba2":
+        out, state = M2.mamba2_prefill(
+            bp["mamba2"], h, _mamba2_state(cfg, x.shape[0]), cfg)
+        cache_entry = {"ssm": state[0], "conv": state[1]}
     elif mixer == "rwkv":
         B = x.shape[0]
         hd = cfg.d_model // cfg.n_heads
@@ -232,22 +308,19 @@ def apply_block(cfg, bp, x, *, mixer: str, ffn: str, positions,
         cache_entry = {"s": state, "last_tm": last_x}
     else:
         raise ValueError(mixer)
-    x = x + out
-    aux = jnp.zeros((), F32)
+    x = _residual(cfg, x, out)
     h = _norm_apply(cfg, bp["ln2"], x)
-    if ffn == "mlp":
-        x = x + L.mlp_block(bp["mlp"], h)
-    elif ffn == "moe":
-        out, aux = L.moe_block(bp["moe"], h, topk=cfg.moe_topk,
-                               impl=moe_impl or cfg.moe_impl,
-                               capacity_factor=cfg.capacity_factor,
-                               shard_ctx=_moe_shard_ctx(cfg))
-        x = x + out
-    elif ffn == "channelmix":
-        out, last_cm = R.channelmix(bp["cm"], h)
-        x = x + out
-        cache_entry["last_cm"] = last_cm
-    return x, aux, cache_entry
+    out, aux, extra = _ffn(cfg, bp, h, ffn, moe_impl or cfg.moe_impl,
+                           _moe_shard_ctx(cfg))
+    cache_entry.update(extra)
+    return _residual(cfg, x, out), aux, cache_entry
+
+
+def _mamba2_state(cfg, B: int):
+    """Zero Mamba-2 state of B rows: (ssm, conv tail), float32."""
+    H, P, N, _, conv_dim = M2.dims(cfg)
+    return (jnp.zeros((B, H, P, N), F32),
+            jnp.zeros((B, M.CONV_K - 1, conv_dim), F32))
 
 
 def forward(cfg, params, inputs: Dict[str, Any], *, collect_cache: bool = False):
@@ -261,10 +334,7 @@ def forward(cfg, params, inputs: Dict[str, Any], *, collect_cache: bool = False)
     if "embeds" in inputs:
         x = inputs["embeds"].astype(cfg.param_dtype)
     else:
-        embed = _constrain_leaf(
-            cfg, ParamSpec((cfg.vocab, cfg.d_model), ("vocab", "embed")),
-            params["embed"])
-        x = embed[inputs["tokens"]]
+        x = _embed(cfg, params, inputs["tokens"])
     B, S = x.shape[0], x.shape[1]
     positions = inputs.get("positions")
     if positions is None:
@@ -316,14 +386,11 @@ def lm_loss(cfg, params, x_final, labels, *, chunk: int = 512):
     nch = x_final.shape[1] // chunk
     xc = x_final.reshape(B, nch, chunk, D).transpose(1, 0, 2, 3)
     lc = labels.reshape(B, nch, chunk).transpose(1, 0, 2)
-    unembed = _constrain_leaf(
-        cfg, ParamSpec((D, cfg.vocab), ("embed", "vocab")), params["unembed"])
 
     def body(carry, inp):
         tot, cnt = carry
         xck, lck = inp
-        logits = jnp.einsum("bsd,dv->bsv", xck.astype(F32),
-                            unembed.astype(F32))
+        logits = _logits(cfg, params, xck)
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(
             logits, jnp.maximum(lck, 0)[..., None], axis=-1)[..., 0]
@@ -339,9 +406,7 @@ def lm_loss(cfg, params, x_final, labels, *, chunk: int = 512):
 
 def lm_logits_last(cfg, params, x_final):
     """Logits of the last position only (prefill -> first generated token)."""
-    xl = x_final[:, -1, :]
-    return jnp.einsum("bd,dv->bv", xl.astype(F32),
-                      params["unembed"].astype(F32))
+    return _logits(cfg, params, x_final[:, -1, :])
 
 
 # ---------------------------------------------------------------------------
@@ -368,6 +433,9 @@ def init_cache(cfg, B: int, max_seq: int) -> Dict[str, Any]:
             elif mx == "mamba":
                 ce = {"ssm": jnp.zeros((B, di, cfg.d_state), F32),
                       "conv": jnp.zeros((B, M.CONV_K - 1, di), F32)}
+            elif mx == "mamba2":
+                ssm, conv = _mamba2_state(cfg, B)
+                ce = {"ssm": ssm, "conv": conv}
             elif mx == "rwkv":
                 ce = {"s": jnp.zeros((B, cfg.n_heads, hd, hd), F32),
                       "last_tm": jnp.zeros((B, cfg.d_model),
@@ -385,30 +453,30 @@ def decode_block(cfg, bp, x, ce, pos, *, mixer: str, ffn: str):
     new_ce = dict(ce)
     if mixer == "attn":
         out, kc, vc = L.attention_decode_stacked(
-            bp["attn"], h, ce["k"], ce["v"], pos, theta=cfg.rope_theta)
+            bp["attn"], h, ce["k"], ce["v"], pos, theta=cfg.rope_theta,
+            use_rope=cfg.rope, scale=cfg.attention_multiplier)
         new_ce["k"], new_ce["v"] = kc, vc
     elif mixer == "mamba":
         out, (ssm, conv) = M.mamba_block(
             bp["mamba"], h, (ce["ssm"], ce["conv"]), cfg.d_state)
         new_ce["ssm"], new_ce["conv"] = ssm, conv
+    elif mixer == "mamba2":
+        out, (ssm, conv) = M2.mamba2_step(
+            bp["mamba2"], h, (ce["ssm"], ce["conv"]), cfg)
+        new_ce["ssm"], new_ce["conv"] = ssm, conv
     elif mixer == "rwkv":
         out, s, last = R.timemix(bp["tm"], h, ce["s"], cfg.n_heads,
                                  x_prev=ce["last_tm"])
         new_ce["s"], new_ce["last_tm"] = s, last.astype(ce["last_tm"].dtype)
-    x = x + out
+    x = _residual(cfg, x, out)
     h = _norm_apply(cfg, bp["ln2"], x)
-    if ffn == "mlp":
-        x = x + L.mlp_block(bp["mlp"], h)
-    elif ffn == "moe":
-        out, _ = L.moe_block(bp["moe"], h, topk=cfg.moe_topk,
-                             impl=cfg.moe_decode_impl,
-                             capacity_factor=cfg.capacity_factor)
-        x = x + out
-    elif ffn == "channelmix":
+    if ffn == "channelmix":
         out, last = R.channelmix(bp["cm"], h, x_prev=ce["last_cm"])
-        x = x + out
         new_ce["last_cm"] = last.astype(ce["last_cm"].dtype)
-    return x, new_ce
+    else:
+        out, _, _ = _ffn(cfg, bp, h, ffn, cfg.moe_decode_impl,
+                         aux_loss=False)
+    return _residual(cfg, x, out), new_ce
 
 
 def decode_step(cfg, params, cache, tokens, pos):
@@ -422,10 +490,7 @@ def decode_step(cfg, params, cache, tokens, pos):
     input.  Scanning with the cache as carry instead double-buffers the
     whole cache each step (§Perf: ~1 TB/step of copies on 32k decode)."""
     pattern = arch_pattern(cfg)
-    embed = _constrain_leaf(
-        cfg, ParamSpec((cfg.vocab, cfg.d_model), ("vocab", "embed")),
-        params["embed"])
-    x = embed[tokens]
+    x = _embed(cfg, params, tokens)
     period_specs = {f"b{i}": block_spec(cfg, mx, ff)
                     for i, (mx, ff) in enumerate(pattern)}
 
@@ -439,9 +504,4 @@ def decode_step(cfg, params, cache, tokens, pos):
                 cfg, bp, x, cache[f"p{j}"][f"b{i}"], pos, mixer=mx, ffn=ff)
         new_cache[f"p{j}"] = new_period
     x = _norm_apply(cfg, params["final_norm"], x)
-    unembed = _constrain_leaf(
-        cfg, ParamSpec((cfg.d_model, cfg.vocab), ("embed", "vocab")),
-        params["unembed"])
-    logits = jnp.einsum("bd,dv->bv", x[:, 0].astype(F32),
-                        unembed.astype(F32))
-    return logits, new_cache
+    return _logits(cfg, params, x[:, 0]), new_cache

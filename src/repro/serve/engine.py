@@ -36,7 +36,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.spans import span
+from repro.core.spans import count, span
 from repro.serve.buckets import BucketPolicy, default_buckets
 from repro.serve.metrics import ServeMetrics
 from repro.serve.scheduler import Request, Scheduler
@@ -113,12 +113,26 @@ class Engine:
             floor=self.config.request_shadow_rate)
         self._req_shadow_ctr = 0
         self.metrics.set_request_shadow_provider(self._request_shadow.snapshot)
+        # a jax-traceable model's cache is donated to every program that
+        # rewrites it (decode, slot install, slot move), so each updates
+        # the cache in place instead of holding a second copy of it
+        donate = ()
+        self._state_row_bytes = 0
+        if self.config.jit_prefill:
+            import jax
+            n_params = len(jax.tree.leaves(params))
+            # one row and one position: the leaves' count and names do not
+            # depend on the bucket
+            cache_sds = jax.eval_shape(lambda: model.init_cache(1, 1))
+            donate = tuple(range(n_params,
+                                 n_params + len(jax.tree.leaves(cache_sds))))
+            self._state_row_bytes = _state_row_bytes(cache_sds)
         if self.config.use_lilac:
             from repro import lilac
             self._decode = lilac.compile(
                 model.decode, mode=self.config.lilac_mode,
                 policy=self.config.policy,
-                plan_cache=self.config.plan_cache)
+                plan_cache=self.config.plan_cache, donate_args=donate)
             info = getattr(self._decode, "resilience_info", None)
             if info is not None:
                 self.metrics.set_resilience_provider(info)
@@ -126,8 +140,11 @@ class Engine:
             self._decode = model.decode
         if self.config.jit_prefill:
             import jax
-            self._prefill = jax.jit(
-                lambda p, toks: model.prefill(p, {"tokens": toks}))
+
+            def _prefill(p, toks):
+                return model.prefill(p, {"tokens": toks})
+
+            self._prefill = jax.jit(_prefill)
             # admission install and eviction compaction as single jitted
             # programs with a *dynamic* slot index: one XLA executable per
             # (prompt-length, bucket) combination, reused for every slot —
@@ -148,8 +165,9 @@ class Engine:
                             a, src, 0, keepdims=False), dst, 0),
                     cache)
 
-            self._install = jax.jit(_install, static_argnums=(3, 4))
-            self._move = jax.jit(_move)
+            self._install = jax.jit(_install, static_argnums=(3, 4),
+                                    donate_argnums=(0,))
+            self._move = jax.jit(_move, donate_argnums=(0,))
         else:
             self._prefill = lambda p, toks: model.prefill(
                 p, {"tokens": toks})
@@ -402,11 +420,17 @@ class Engine:
                 t0 = self.clock()
                 logits, caches = self._prefill(self.params,
                                                req.prompt[None, :])
-                self._cache = self._install(self._cache, caches, slot,
-                                            req.prompt_len, self._shape[1])
+                with span("serve.install", rid=req.rid,
+                          state_bytes=self._state_row_bytes):
+                    self._cache = self._install(self._cache, caches, slot,
+                                                req.prompt_len,
+                                                self._shape[1])
+                count("serve.state_bytes_installed", self._state_row_bytes)
                 with span("serve.readback"):
                     logits_np = np.asarray(logits)
-                req.tokens.append(int(np.argmax(logits_np[0])))
+                tok = int(np.argmax(logits_np[0]))
+                req.tokens.append(tok)
+                req.token_logits.append(float(logits_np[0, tok]))
                 req.prefill_s = self.clock() - t0
                 req.ttft_s = self.clock() - req.arrival_t
                 self.metrics.record_admit(req.rid, req.prefill_s, req.ttft_s)
@@ -442,6 +466,7 @@ class Engine:
                 # the cache was NOT reassigned, so this step is a no-op for
                 # the survivors: they redo the identical decode next step
                 # and their streams stay bit-identical to a fault-free run
+                # (a fault raised before the donated call consumed it)
                 return
             dt = self.clock() - t0
         with span("serve.readback"):
@@ -460,12 +485,14 @@ class Engine:
             finite = np.isfinite(
                 logits_np.reshape(logits_np.shape[0], -1)).all(axis=1)
             nxt = np.argmax(logits_np, axis=-1)
+            picked = np.take_along_axis(logits_np, nxt[:, None], -1)[:, 0]
             for i, r in enumerate(active):
                 if not finite[i]:
                     r.failed = "non-finite decode logits"
                     self.metrics.record_decode_fault()
                     continue
                 r.tokens.append(int(nxt[i]))
+                r.token_logits.append(float(picked[i]))
                 r.decode_shapes.append((tb, ts))
             self.metrics.record_step(
                 dt, batch=tb, active=len(active),
@@ -502,6 +529,7 @@ class Engine:
             finished, moves = self.scheduler.evict_finished()
             for src, dst in moves:
                 self._cache = self._move(self._cache, src, dst)
+                count("serve.state_bytes_moved", self._state_row_bytes)
             now = self.clock()
             for r in finished:
                 r.finish_t = now
@@ -544,6 +572,17 @@ class Engine:
         report = getattr(self._decode, "report_divergence", None)
         if report is not None:
             report(reason=f"request-shadow divergence (rid {req.rid})")
+
+
+def _state_row_bytes(cache_shapes) -> int:
+    """Bytes of one cache row's recurrent state: every leaf but the
+    attention keys and values, whose length grows with the sequence."""
+    import jax
+    total = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache_shapes):
+        if getattr(path[-1], "key", None) not in ("k", "v"):
+            total += int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+    return total
 
 
 def build_engine(arch: str = "olmoe-1b-7b", *, smoke: bool = True,

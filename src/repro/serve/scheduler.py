@@ -53,6 +53,8 @@ class Request:                        # stateful records, not values
     deadline_s: Optional[float] = None
     # filled by the engine as the request progresses
     tokens: List[int] = dataclasses.field(default_factory=list)
+    # the logit of each of tokens, as the step that chose it computed it
+    token_logits: List[float] = dataclasses.field(default_factory=list)
     # (batch, seq) bucket of the decode step behind each of tokens[1:]
     decode_shapes: List[Tuple[int, int]] = dataclasses.field(
         default_factory=list)

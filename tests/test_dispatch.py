@@ -458,6 +458,29 @@ def test_donate_args_rejects_marshal_sources():
         acc(csr.val, csr.col_ind, csr.row_ptr, vec)
 
 
+@pytest.mark.parametrize("policy,first_baked", [("jnp.segment", True),
+                                                 ("jnp.ell", False)])
+def test_donated_first_call(policy, first_baked):
+    """A donated signature's first call is recorded with the donated
+    operands abstract and served by the baked plan, which consumes them;
+    a harness that marshals (``jnp.ell``) keeps the eager first call."""
+    csr, vec = _problem()
+    naive = _naive_fn(csr.rows, csr.nnz)
+    acc = lilac.compile(naive, mode="host", policy=policy,
+                        plan_cache=False, donate_args=(3,))  # 3 = v
+    ref = naive(csr.val, csr.col_ind, csr.row_ptr, vec)
+    for call in range(2):
+        v = jnp.array(vec)
+        out = acc(csr.val, csr.col_ind, csr.row_ptr, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+        served = first_baked or call > 0
+        assert v.is_deleted() is served
+        assert acc.plan_info()["plan_hits"] == (call + 1 if first_baked
+                                                else call)
+        assert [n for _, n in acc.last_selections] == [policy]
+
+
 # ---------------------------------------------------------------------------
 # serialization + persistent plan cache
 # ---------------------------------------------------------------------------

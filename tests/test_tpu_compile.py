@@ -120,6 +120,22 @@ def test_gmm_compiles(one_chip, dimsem, D, F):
                       ((Tp // tm,), jnp.int32))
 
 
+@pytest.mark.parametrize("dimsem", DIMSEMS)
+def test_held_share_gmm_compiles(one_chip, dimsem):
+    """granite-4.0-h-small's held share (9 of 72 experts, d_model 4096,
+    expert width 768, top-10 at batch 96) through the whole ``moe_ffn``:
+    routing with absent ids, the three grouped matmuls with their tiles
+    past the held pairs skipped, the combine."""
+    from repro.kernels.moe_gmm import ops as gmm_ops
+    T, K, E, D, F = 96, 10, 9, 4096, 768
+    fn = functools.partial(gmm_ops.moe_ffn, tm=128, fn=128, dk=128,
+                           dimension_semantics=dimsem)
+    _compile_for_chip(fn, one_chip,
+                      ((T, D), jnp.bfloat16), ((T, K), jnp.bfloat16),
+                      ((T, K), jnp.int32), ((E, D, F), jnp.bfloat16),
+                      ((E, D, F), jnp.bfloat16), ((E, F, D), jnp.bfloat16))
+
+
 def test_dia_spmv_compiles(one_chip):
     """HPCG-104's 27-point stencil in diagonal storage (n = 104^3, 27
     diagonals): shifted slices of x and elementwise multiply-adds only."""
